@@ -28,8 +28,10 @@ and fails on drift, exactly like the BENCH_shard.json guard.  Wall times
 are recorded for context but excluded from the guard.  The crossover is
 explained in DESIGN.md section 12.
 
-The measurement runs in a subprocess that forces 8 XLA host devices before
-jax initializes, so the benchmark works from any session.
+The measurement is a CPU counter: it runs in a subprocess pinned to
+``JAX_PLATFORMS=cpu`` that forces 8 XLA host devices before jax
+initializes.  Its parent has already imported JAX (and holds the chip
+where there is one), so the child never asks for an accelerator.
 """
 from __future__ import annotations
 
@@ -125,7 +127,7 @@ def run(out: str = OUT):
     env = dict(
         os.environ,
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+        JAX_PLATFORMS="cpu",
     )
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_granularity", "--child"],

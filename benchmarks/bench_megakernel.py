@@ -33,6 +33,11 @@ jaxpr-in-kernel body yet, DESIGN.md section 14), so its wall seconds are
 an emulation artifact on every backend — the counters and the parity bit
 are the portable signal, and the roofline terms bound what a future
 compiled lowering would have to beat.
+
+The measurement is a CPU counter: it runs in a subprocess pinned to
+``JAX_PLATFORMS=cpu``.  Its parent has already imported JAX (and holds
+the chip where there is one), so the child never asks for an
+accelerator.
 """
 from __future__ import annotations
 
@@ -162,8 +167,7 @@ def _child() -> None:
 
 
 def run(out: str = OUT):
-    env = dict(os.environ,
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_megakernel", "--child"],
         capture_output=True, text=True, env=env, timeout=1800)

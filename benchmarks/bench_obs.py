@@ -18,6 +18,11 @@ The traced BFS run's artifacts are emitted alongside the JSON:
 and ``BENCH_obs_metrics.jsonl`` (canonical metrics docs: meta, run
 summary, spans, per-round records), both validated against
 ``repro/obs/schema.py`` at emission time and again by the smoke guard.
+
+The measurement is a CPU counter: it runs in a subprocess pinned to
+``JAX_PLATFORMS=cpu``.  Its parent has already imported JAX (and holds
+the chip where there is one), so the child never asks for an
+accelerator.
 """
 from __future__ import annotations
 
@@ -132,8 +137,7 @@ def _child() -> None:
 
 
 def run(out: str = OUT):
-    env = dict(os.environ,
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_obs", "--child",
          json.dumps(bench_meta())],

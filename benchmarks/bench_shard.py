@@ -23,9 +23,10 @@ observable.  Emits ``BENCH_shard.json`` with, per (graph, shard count):
     reproduces the exhaustive grid's pick under the deterministic
     structural runner while measuring <= 1/4 of the cells.
 
-The measurement itself runs in a subprocess that forces 8 XLA host devices
-before jax initializes, so the benchmark works from any session (the parent
-process may already hold a 1-device backend).
+The measurement is a CPU counter: it runs in a subprocess pinned to
+``JAX_PLATFORMS=cpu`` that forces 8 XLA host devices before jax
+initializes.  Its parent has already imported JAX (and holds the chip
+where there is one), so the child never asks for an accelerator.
 """
 from __future__ import annotations
 
@@ -193,7 +194,7 @@ def run(out: str = OUT):
     env = dict(
         os.environ,
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+        JAX_PLATFORMS="cpu",
     )
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_shard", "--child"],

@@ -36,8 +36,10 @@ All rounds/work/seed counters are schedule-deterministic, so
 ``benchmarks/smoke.py`` recomputes them in CI and fails on drift, exactly
 like the BENCH_shard.json / BENCH_granularity.json guards.
 
-The measurement runs in a subprocess that forces 8 XLA host devices before
-jax initializes, so the benchmark works from any session.
+The measurement is a CPU counter: it runs in a subprocess pinned to
+``JAX_PLATFORMS=cpu`` that forces 8 XLA host devices before jax
+initializes.  Its parent has already imported JAX (and holds the chip
+where there is one), so the child never asks for an accelerator.
 """
 from __future__ import annotations
 
@@ -189,7 +191,7 @@ def run(out: str = OUT):
     env = dict(
         os.environ,
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+        JAX_PLATFORMS="cpu",
     )
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_stream", "--child"],

@@ -62,14 +62,10 @@ def bench_meta() -> dict:
             timeout=5, check=True).stdout.strip()
     except Exception:
         git_sha = "unknown"
-    try:
-        device_kind = str(jax.devices()[0].device_kind)
-    except Exception:
-        device_kind = "unknown"
     return {
         "git_sha": git_sha,
         "jax_version": jax.__version__,
-        "device_kind": device_kind,
+        "device_kind": str(jax.devices()[0].device_kind),
         "python": platform.python_version(),
         "schema": SCHEMA_VERSION,
     }

@@ -16,6 +16,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     argv = sys.argv[1:]
     if "--smoke" in argv:
         extra = [a for a in argv if a != "--smoke"]

@@ -100,7 +100,7 @@ def _recompute() -> dict:
     body = f"""
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'
 import json
 import numpy as np
 from repro.core import SchedulerConfig
@@ -207,7 +207,7 @@ def _recompute_granularity() -> dict:
     body = f"""
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'
 import json
 import numpy as np
 from repro.algorithms.pagerank import pagerank_async
@@ -269,7 +269,7 @@ def _recompute_stream() -> dict:
     body = f"""
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'
 import json
 import numpy as np
 from repro.core import SchedulerConfig
@@ -330,7 +330,7 @@ def _recompute_megakernel() -> dict:
 
     body = f"""
 import os
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'
 import json
 import numpy as np
 from repro.core import SchedulerConfig
@@ -379,7 +379,7 @@ def _recompute_obs() -> dict:
 
     body = f"""
 import os
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+os.environ['JAX_PLATFORMS'] = 'cpu'
 import json
 import numpy as np
 from repro.core import SchedulerConfig

@@ -154,7 +154,12 @@ def make_wavefront_fn(graph: CSRGraph, strategy: str, work_budget: int,
         # only on the merge_path branch.)
         live = (ex.valid & ~truncated[ex.owner] if strategy == "merge_path"
                 else ex.valid)
-        cand = jnp.where(live, state.dist[ex.src] + 1, INF)
+        # one-row chunks: a unit's source is its popped head, so its
+        # distance comes from the wavefront-sized table
+        src_dist = (state.dist[heads][ex.owner]
+                    if strategy == "merge_path" and g == 1
+                    else state.dist[ex.src])
+        cand = jnp.where(live, src_dist + 1, INF)
         before = state.dist[ex.nbr]
         tgt = jnp.where(live, ex.nbr, 0)
         new_dist = state.dist.at[tgt].min(jnp.where(live, cand, INF),

@@ -19,8 +19,9 @@ GPU->TPU adaptations (DESIGN.md):
     re-pick the same color).  We use the standard ID tie-break: the
     higher-ID endpoint re-colors.  Same fixed point, guaranteed progress.
   * forbidden-color bitset — CUDA builds a shared-memory forbidden array per
-    vertex; we build a [wavefront, max_colors] one-hot table and take argmin
-    (vectorizes over the 8x128 VPU).
+    vertex; we scatter the wavefront's merge-path-expanded neighbor colors
+    into a [wavefront, max_colors] table and take argmin (vectorizes over
+    the 8x128 VPU).
 """
 from __future__ import annotations
 
@@ -31,12 +32,12 @@ import jax
 import jax.numpy as jnp
 
 from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, adjacency_of,
-                    chunk_seeds, coalesce_chunks, flatten_chunks,
-                    gather_neighbors)
+                    chunk_degrees, chunk_seeds, coalesce_chunks,
+                    expand_merge_path, flatten_chunks, gather_neighbors)
 from ..graph.csr import CSRGraph
 from ..runtime.program import AtosProgram, ProgramContext
 from ..runtime.programs import reject_unknown_params
-from .common import chunking_for, max_degree_of
+from .common import chunking_for, default_work_budget, max_degree_of
 
 
 @jax.tree_util.register_dataclass
@@ -60,6 +61,36 @@ def _gather_neighbor_colors(graph, vids, valid, max_degree):
     return nbr, in_row
 
 
+#: width of the first forbidden-color table; wider palettes are only
+#: scanned in the rounds where some lane has every color below it taken
+FIRST_COLORS = 1024
+
+
+def _first_free_color(lane, nbr_colors, live, num_lanes, max_colors):
+    """Per lane: smallest color in [0, max_colors) on none of its live edges.
+
+    The forbidden table is built by one scatter over the edges — O(edges +
+    lanes x colors), never the [lanes, edges, colors] one-hot, which at a
+    Graph500 hub degree would not fit any device.  The table first spans
+    only ``FIRST_COLORS`` colors; the full ``max_colors`` table is built
+    only when a lane finds all of those taken, so the answer is the same.
+    """
+    seen = live & (nbr_colors >= 0)
+
+    def first_free(width):
+        forbidden = jnp.zeros((num_lanes, width), jnp.bool_).at[
+            jnp.where(seen, lane, num_lanes), jnp.where(seen, nbr_colors, 0)
+        ].set(True, mode="drop")
+        return (jnp.argmin(forbidden, axis=1).astype(jnp.int32),  # 1st False
+                jnp.all(forbidden, axis=1))
+
+    if max_colors <= FIRST_COLORS:
+        return first_free(max_colors)[0]
+    pick, full = first_free(FIRST_COLORS)
+    return jax.lax.cond(jnp.any(full), lambda: first_free(max_colors)[0],
+                        lambda: pick)
+
+
 def _min_free_color(colors, nbr, in_row, max_colors):
     """Per row: smallest color in [0, max_colors) unused by valid neighbors."""
     nbr_colors = jnp.where(in_row, colors[nbr], -1)          # [w, d]
@@ -78,15 +109,19 @@ def _priority(v):
     return h ^ (h >> 16)
 
 
+def _loses(v, u):
+    """Does ``v`` yield to neighbor ``u``?  Total order (hash, id) — id
+    breaks the (rare) hash collisions."""
+    pv, pu = _priority(v), _priority(u)
+    return (pu < pv) | ((pu == pv) & (u < v))
+
+
 def _conflicts(colors, vids, valid, nbr, in_row):
     """Does v share a color with a higher-priority neighbor? (v recolors)."""
     safe = jnp.where(valid, vids, 0)
     my = colors[safe]
-    pv, pn = _priority(safe)[:, None], _priority(nbr)
-    # total order: (hash, id) — id breaks the (rare) hash collisions
-    loses = (pn < pv) | ((pn == pv) & (nbr < safe[:, None]))
-    clash = in_row & (colors[nbr] == my[:, None]) & loses & \
-        (my[:, None] >= 0)
+    clash = in_row & (colors[nbr] == my[:, None]) & \
+        _loses(safe[:, None], nbr) & (my[:, None] >= 0)
     return jnp.any(clash, axis=1) & valid
 
 
@@ -151,8 +186,10 @@ def init_state(graph: CSRGraph,
     return state, jnp.asarray(chunks) + 1
 
 
-def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
+def make_wavefront_fn(graph: CSRGraph, work_budget: int,
+                      fused: bool = True,
                       max_degree: int | None = None,
+                      backend: str = "jnp",
                       codec: ChunkCodec | None = None,
                       split_threshold: int | None = None,
                       owner_block: int | None = None,
@@ -169,6 +206,14 @@ def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
     shared by the single-tenant driver (``coloring_async``) and the task
     server.
 
+    Both phases read the wavefront's neighbors through one merge-path
+    expansion of at most ``work_budget`` edges (the BFS discipline):
+    chunks whose rows spill past the budget are re-queued whole and
+    unchanged, so per-round work follows the degrees actually popped, not
+    ``wavefront x max_degree``.  Progress is guaranteed because the budget
+    is at least the largest chunk degree-sum.  ``backend`` selects the LBS
+    implementation, with bit-identical colors either way.
+
     ``fused=False`` makes phase B read the *pre-wavefront* colors instead of
     phase A's same-wavefront commits.  The sharded driver (repro/shard)
     needs this: remote assigns from the same epoch are invisible anyway, so
@@ -177,12 +222,6 @@ def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
     (DESIGN.md section 10).  ``max_degree`` may be passed explicitly when
     the body is built inside a traced context (a shard_map) where the
     device-local CSR slice cannot be concretized.
-
-    Backend note (DESIGN.md section 9): coloring's expansion is the padded
-    per-item gather, not merge-path LBS, so the body itself has no kernel
-    dispatch.  Under ``SchedulerConfig(backend="pallas")`` the algorithm
-    still exercises the Pallas hot path through the scheduler's queue push
-    (``kernels/queue_compact``), with bit-identical colors (tested).
     """
     n = graph.num_vertices
     if max_degree is None:
@@ -192,6 +231,7 @@ def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
     g = codec.granularity
     form_rp = (graph.row_ptr if formation_row_ptr is None
                else formation_row_ptr)
+    rp, cols, overlay = adjacency_of(graph)
 
     def f(items, valid, state: ColorState):
         is_assign = valid & (items > 0)
@@ -199,17 +239,28 @@ def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
         codes = jnp.where(is_assign, items - 1, -items - 1)
         codes = jnp.where(valid, codes, 0)
         heads, widths = codec.decode(codes)
+        deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
+        excl = jnp.cumsum(deg) - deg
+        truncated = valid & (excl + deg > work_budget)
+        ex = expand_merge_path(heads, valid, rp, cols, work_budget,
+                               backend=backend, widths=widths, max_width=g,
+                               overlay=overlay)
+        live = ex.valid & ~truncated[ex.owner]
         # explode chunk tasks into their member vertices: lane kind (assign
-        # vs detect) is a chunk property, vertices are per member
+        # vs detect) is a chunk property, vertices are per member; each
+        # edge's lane is its source row's slot in that layout
         vids, flat_valid, owner = flatten_chunks(heads, widths, valid, g)
-        flat_assign = flat_valid & is_assign[owner]
-        flat_detect = flat_valid & is_detect[owner]
+        flat_run = flat_valid & ~truncated[owner]
+        flat_assign = flat_run & is_assign[owner]
+        flat_detect = flat_run & is_detect[owner]
+        lane = ex.owner * g + (ex.src - heads[ex.owner])
+        num_lanes = vids.shape[0]
 
         # ---- phase A: assigns (all reads see pre-wavefront colors = stale
         # speculation, exactly the GPU race the paper analyzes)
-        nbr, in_row = _gather_neighbor_colors(graph, vids, flat_assign,
-                                              max_degree)
-        pick = _min_free_color(state.colors, nbr, in_row, max_colors)
+        pick = _first_free_color(lane, state.colors[ex.nbr],
+                                 live & is_assign[ex.owner], num_lanes,
+                                 max_colors)
         # duplicate assign tasks for one vertex cannot exist (1 assign ->
         # 1 detect -> at most 1 re-assign, and chunk members are distinct),
         # so this scatter has unique targets
@@ -220,21 +271,28 @@ def make_wavefront_fn(graph: CSRGraph, fused: bool = True,
         # (uberkernel fusion: later tasks see earlier tasks' commits).  The
         # unfused variant reads epoch-start colors so detection is identical
         # no matter which device processed the wavefront (shard parity).
-        nbr_d, in_row_d = _gather_neighbor_colors(graph, vids, flat_detect,
-                                                  max_degree)
         detect_colors = colors if fused else state.colors
-        bad = _conflicts(detect_colors, vids, flat_detect, nbr_d, in_row_d)
+        my = (detect_colors[heads][ex.owner] if g == 1
+              else detect_colors[ex.src])
+        clash = (live & is_detect[ex.owner] & (my >= 0)
+                 & (detect_colors[ex.nbr] == my) & _loses(ex.src, ex.nbr))
+        bad = jnp.zeros((num_lanes,), jnp.bool_).at[
+            jnp.where(clash, lane, num_lanes)].set(True, mode="drop")
+        bad &= flat_detect
 
         # conflicted vertices re-coalesce into assign chunks (identity at
-        # G = 1: each bad vertex re-assigns alone, exactly the old stream)
+        # G = 1: each bad vertex re-assigns alone, exactly the old stream);
+        # truncated chunks are re-queued whole, unchanged.
         re_assign, re_mask, n_splits = coalesce_chunks(
             vids, bad, codec, form_rp, split_threshold=split_threshold,
             owner_block=owner_block)
+        done_assign = is_assign & ~truncated
         out = jnp.concatenate([
-            jnp.where(is_assign, -(codes + 1), 0),  # assign -> queue a detect
-            jnp.where(re_mask, re_assign + 1, 0),   # conflict -> re-assign
+            jnp.where(done_assign, -(codes + 1), 0),  # assign -> a detect
+            jnp.where(re_mask, re_assign + 1, 0),     # conflict -> re-assign
+            jnp.where(truncated, items, 0),
         ])
-        mask = jnp.concatenate([is_assign, re_mask])
+        mask = jnp.concatenate([done_assign, re_mask, truncated])
         counter = state.counter.add(jnp.sum(flat_assign.astype(jnp.int32)))
         counter = counter.add_splits(n_splits)
         return out, mask, ColorState(colors=colors, counter=counter)
@@ -262,8 +320,11 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
     minimal work, but a *different* valid coloring than a from-scratch
     drain); ``"recolor"`` disables the rule, so delta batches trigger the
     conservative full reseed (bit-identical to from-scratch).
+    ``work_budget`` caps the edges one wavefront expands, as for BFS and
+    PageRank (default: :func:`~repro.algorithms.common.default_work_budget`).
     """
     dirty = params.pop("dirty", "conflicts")
+    work_budget = params.pop("work_budget", None)
     reject_unknown_params("coloring", params)
     if dirty not in ("conflicts", "recolor"):
         raise ValueError(
@@ -271,11 +332,14 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
             f"got {dirty!r}")
     n = graph.num_vertices
     max_degree = max_degree_of(graph)
-    codec, threshold, owner_block = chunking_for(graph, cfg)
+    budget = default_work_budget(graph, cfg.wavefront, work_budget,
+                                 max_degree=max_degree)
+    codec, threshold, owner_block = chunking_for(graph, cfg, budget)
 
     def make_body(local_graph: CSRGraph, ctx: ProgramContext):
-        return make_wavefront_fn(local_graph, fused=not ctx.sharded,
-                                 max_degree=max_degree, codec=codec,
+        return make_wavefront_fn(local_graph, budget, fused=not ctx.sharded,
+                                 max_degree=max_degree, backend=ctx.backend,
+                                 codec=codec,
                                  split_threshold=threshold,
                                  owner_block=owner_block,
                                  formation_row_ptr=graph.row_ptr)

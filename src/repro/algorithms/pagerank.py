@@ -114,11 +114,16 @@ def _push_wavefront(graph: CSRGraph, damping: float, work_budget: int,
                                widths=widths, max_width=g, overlay=overlay)
         # per-edge contribution from the edge's true source row: ex.src is
         # the chunk member owning the edge, its residue read pre-harvest.
+        # One-row chunks share it per popped task: computed on the
+        # wavefront, then read per edge from that [k] table.
+        src = heads if g == 1 else ex.src
         row_deg = jnp.maximum(
-            graph.row_ptr[ex.src + 1] - graph.row_ptr[ex.src], 1
+            graph.row_ptr[src + 1] - graph.row_ptr[src], 1
         ).astype(jnp.float32)
-        res_src = jnp.where(popped[ex.src], state.residue[ex.src], 0.0)
-        contrib = jnp.where(ex.valid, damping * res_src / row_deg, 0.0)
+        res_src = jnp.where(popped[src], state.residue[src], 0.0)
+        contrib = damping * res_src / row_deg
+        contrib = jnp.where(ex.valid,
+                            contrib[ex.owner] if g == 1 else contrib, 0.0)
         residue = residue.at[jnp.where(ex.valid, ex.nbr, 0)].add(contrib,
                                                                  mode="drop")
         counter = state.counter.add(jnp.sum(jnp.where(process, widths, 0)))

@@ -36,8 +36,6 @@ before resolution, and the same interpret-mode fallback applies off-TPU.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 
 #: the public axis values, in the order they appear in CLIs and docs.
@@ -50,13 +48,10 @@ BACKENDS = ("jnp", "pallas", "auto")
 STREAM = "stream"
 
 
-@functools.lru_cache(maxsize=1)
 def has_tpu() -> bool:
-    """True when the default JAX backend exposes at least one TPU device."""
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:  # no devices / uninitialized backend: act portable
-        return False
+    """True when JAX's default backend is the TPU.  A backend that fails
+    to initialize raises here instead of passing for a CPU."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_backend(backend: str) -> str:

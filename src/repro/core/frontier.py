@@ -53,6 +53,16 @@ def searchsorted_right(sorted_arr: jax.Array, values: jax.Array) -> jax.Array:
     return lo
 
 
+def owner_of_units(scan: jax.Array, budget: int) -> jax.Array:
+    """``searchsorted_right(scan, arange(budget))`` for a non-decreasing
+    ``scan`` of non-negative ints: the number of scan entries <= k, as one
+    scatter-add of ones at the scan values and a prefix sum over the
+    budget (no per-unit search; sorted indices, so no scatter sort)."""
+    marks = jnp.zeros((budget,), jnp.int32).at[scan].add(
+        1, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(marks)
+
+
 def adjacency_of(graph) -> Tuple[jax.Array, jax.Array, object]:
     """``(row_ptr, cols, overlay)`` of a canonical or slotted graph.
 
@@ -200,7 +210,7 @@ def expand_merge_path(
     total = scan[-1] if scan.shape[0] > 0 else jnp.int32(0)
 
     k = jnp.arange(work_budget, dtype=jnp.int32)
-    owner = searchsorted_right(scan, k)          # which popped item owns unit k
+    owner = owner_of_units(scan, work_budget)    # which popped item owns unit k
     owner = jnp.clip(owner, 0, items.shape[0] - 1)
     excl = scan - deg                            # exclusive scan
     rank = k - excl[owner]                       # edge offset within the chunk
@@ -208,7 +218,8 @@ def expand_merge_path(
     src = (head if widths is None else
            chunk_row_of(row_ptr, head, rank, widths[owner], max_width))
     in_range = k < total
-    edge = row_ptr[head] + rank
+    # per-unit reads index the wavefront-sized tables, not the [n] row_ptr
+    edge = (row_ptr[safe] - excl)[owner] + k
     nbr = gather_neighbors(row_ptr, col_idx, src, edge, overlay=overlay)
     return Expansion(
         src=jnp.where(in_range, src, 0),

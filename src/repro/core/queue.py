@@ -129,61 +129,69 @@ class TaskQueue:
              backend: str = "jnp") -> "TaskQueue":
         """Push ``items[mask]`` — prefix-sum slot reservation.
 
-        Each valid item i gets slot ``tail + excl_cumsum(mask)[i]``; one
-        vectorized scatter commits the wavefront.  Items beyond capacity are
-        dropped and counted (Atos's queue is sized to never overflow; we keep
-        the counter so tests & benchmarks can assert no drops happened).
+        Each valid item i gets slot ``tail + excl_cumsum(mask)[i]``: the
+        valid items are compacted in order and committed as one contiguous
+        run of the ring.  Items beyond capacity are dropped and counted
+        (Atos's queue is sized to never overflow; we keep the counter so
+        tests & benchmarks can assert no drops happened).
 
-        ``backend="pallas"`` routes the reservation through the Pallas
-        stream-compaction kernel (``kernels/queue_compact``); the resulting
-        queue pytree — buffer contents, cursors, dropped counter — is
-        bit-identical to the jnp path (tested in tests/test_backend.py).
+        ``backend="pallas"`` compacts through the Pallas stream-compaction
+        kernel (``kernels/queue_compact``) instead of the jnp prefix sum;
+        the resulting queue pytree — buffer contents, cursors, dropped
+        counter — is bit-identical to the jnp path (tested in
+        tests/test_backend.py).
         """
         if resolve_backend(backend) == "pallas":
-            return self._push_pallas(items, mask)
-        mask = mask.astype(jnp.int32)
-        offs = jnp.cumsum(mask) - mask  # exclusive prefix sum
-        free = self.capacity - self.size
-        will_fit = (offs < free) & (mask > 0)
-        slots = (self.tail + offs) % self.capacity
-        # scatter only surviving items; drop others
-        buf = self.buf.at[jnp.where(will_fit, slots, self.capacity)].set(
-            items, mode="drop"
-        )
-        n_push = jnp.sum(will_fit.astype(jnp.int32))
-        n_drop = jnp.sum(mask) - n_push
-        return dataclasses.replace(
-            self, buf=buf, tail=self.tail + n_push, dropped=self.dropped + n_drop
-        )
+            from ..kernels.queue_compact.ops import compact  # lazy: kernels->core
 
-    def _push_pallas(self, items: jax.Array, mask: jax.Array) -> "TaskQueue":
-        """Kernel-backed push: compact valid items, then one contiguous write.
-
-        The compaction kernel assigns valid item i the same rank the jnp
-        path's exclusive prefix sum does, so the first ``free`` valid items
-        land in the same slots with the same values and the overflow
-        accounting matches exactly.
-        """
-        from ..kernels.queue_compact.ops import compact  # lazy: kernels->core
-
-        compacted, count = compact(items, mask.astype(bool))
+            compacted, count = compact(items, mask.astype(bool))
+        else:
+            mask = mask.astype(jnp.int32)
+            offs = jnp.cumsum(mask) - mask  # exclusive prefix sum
+            # invalid items add 0 at the next valid item's slot, so the
+            # indices stay sorted and no sort precedes the scatter
+            compacted = jnp.zeros_like(items).at[offs].add(
+                jnp.where(mask > 0, items, 0), mode="drop",
+                indices_are_sorted=True)
+            count = jnp.sum(mask)
         free = self.capacity - self.size
         n_push = jnp.minimum(count, free)
-        j = jnp.arange(items.shape[0], dtype=jnp.int32)
-        live = j < n_push
-        slots = (self.tail + j) % self.capacity
-        buf = self.buf.at[jnp.where(live, slots, self.capacity)].set(
-            compacted, mode="drop"
-        )
         return dataclasses.replace(
-            self, buf=buf, tail=self.tail + n_push,
-            dropped=self.dropped + (count - n_push)
-        )
+            self, buf=_ring_write(self.buf, self.tail, compacted, n_push),
+            tail=self.tail + n_push, dropped=self.dropped + (count - n_push))
 
     def push_dense(self, items: jax.Array, backend: str = "jnp") -> "TaskQueue":
         """Push every element of ``items`` (all valid)."""
         return self.push(items, jnp.ones(items.shape, dtype=bool),
                          backend=backend)
+
+
+def _ring_write(buf: jax.Array, start, vals: jax.Array, n) -> jax.Array:
+    """``buf[(start + j) % capacity] = vals[j]`` for ``j < n``.
+
+    Two masked contiguous windows (the run before the ring's end, then
+    its wrapped remainder) instead of a scatter: a TPU scatter first sorts
+    its indices.  A ring narrower than ``vals`` takes the scatter.
+    """
+    cap, k = buf.shape[0], vals.shape[0]
+    j = jnp.arange(k, dtype=jnp.int32)
+    t = start % cap
+    if k > cap:
+        return buf.at[jnp.where(j < n, (t + j) % cap, cap)].set(
+            vals, mode="drop")
+    pad = jnp.zeros((k,), vals.dtype)
+    ext = jnp.concatenate([pad, vals, pad])     # ext[k + i] = vals[i]
+    for lo, src in ((jnp.minimum(t, cap - k), None), (jnp.int32(0), cap - t)):
+        # ring slot lo + j holds vals[lo + j - t] (window 1) or, past the
+        # wrap, vals[j + cap - t] (window 2)
+        off = lo - t if src is None else src
+        want = ((j + off >= 0) & (j + off < n) if src is None
+                else j + off < n)
+        old = jax.lax.dynamic_slice(buf, (lo,), (k,))
+        new = jax.lax.dynamic_slice(ext, (k + off,), (k,))
+        buf = jax.lax.dynamic_update_slice(buf, jnp.where(want, new, old),
+                                           (lo,))
+    return buf
 
 
 def make_queue(capacity: int, init_items: jax.Array | None = None) -> TaskQueue:

@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
 
 
@@ -40,10 +39,9 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *, mesh: Mesh,
         p = jax.tree.map(lambda a: a[0], params_local)
         s = jax.lax.axis_index(axis)
         # the carry is stage-varying (each stage holds a different
-        # activation); mark the initial zeros accordingly.  jax < 0.5 has no
-        # pvary (no varying-manual-axes tracking) and needs no annotation.
-        pvary = getattr(jax.lax, "pvary", lambda v, _axes: v)
-        zero_act = pvary(jnp.zeros_like(x_all[0]), (axis,))
+        # activation); mark the initial zeros accordingly.
+        zero_act = jax.lax.pcast(jnp.zeros_like(x_all[0]), (axis,),
+                                 to="varying")
 
         def tick(carry, t):
             act_in = carry
@@ -62,12 +60,16 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *, mesh: Mesh,
         window = jax.lax.dynamic_slice_in_dim(outs, start, n_micro, axis=0)
         return window[None]  # [1, M, mb, ...] per stage
 
-    out = shard_map(
-        body, mesh=mesh,
-        in_specs=(PS(axis), PS()),
-        out_specs=PS(axis),
-    )(stage_params, x_micro)
-    return out[-1]  # last stage's microbatch outputs
+    # ``jax.make_mesh`` builds explicit axes, whose arrays index only
+    # inside a mesh context
+    with jax.set_mesh(mesh):
+        out = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(PS(axis), PS()),
+            out_specs=PS(axis),
+        )(stage_params, x_micro)
+        # last stage's microbatch outputs, replicated
+        return out.at[-1].get(out_sharding=PS())
 
 
 def split_microbatches(x, n_micro: int):

@@ -24,15 +24,22 @@ def rmat(scale: int, edge_factor: int = 16, seed: int = 0,
     rng = np.random.default_rng(seed)
     n = 1 << scale
     m = n * edge_factor
-    src = np.zeros(m, dtype=np.int64)
-    dst = np.zeros(m, dtype=np.int64)
+    src = np.zeros(m, dtype=np.int32)
+    dst = np.zeros(m, dtype=np.int32)
+    r = np.empty(m)
+    quadrant = np.empty(m, dtype=np.uint8)
     for bit in range(scale):
-        r = rng.random(m)
-        # quadrant probabilities a, b, c, d
-        src_bit = (r >= a + b).astype(np.int64)
-        dst_bit = (((r >= a) & (r < a + b)) | (r >= a + b + c)).astype(np.int64)
-        src = (src << 1) | src_bit
-        dst = (dst << 1) | dst_bit
+        rng.random(out=r)
+        # quadrant 0..3 with probabilities a, b, c, d: its high bit is the
+        # source bit, its low bit the destination bit (in-place, so a
+        # Graph500-size graph does not stream int64 temporaries)
+        np.greater_equal(r, a, out=quadrant, casting="unsafe")
+        quadrant += r >= a + b
+        quadrant += r >= a + b + c
+        src <<= 1
+        src |= quadrant >> 1
+        dst <<= 1
+        dst |= quadrant & 1
     return from_edges(n, src, dst, symmetrize=True)
 
 

@@ -23,10 +23,10 @@ global-empty check — into one ``pallas_call``:
 Unlike the leaf kernels in this tree, the fused drain body is an
 **interpret-mode prototype**: its jaxpr contains a nested ``pallas_call``
 (the DMA stream) and whole-array operands that Mosaic has no in-kernel
-lowering for, so ``fused_drain_pallas`` ALWAYS runs through the Pallas
+lowering for, so ``fused_drain_pallas`` only runs through the Pallas
 interpreter — on a real TPU (where ``core.backend.resolve_interpret``
-would compile) it warns and falls back, and an explicit
-``interpret=False`` raises ``NotImplementedError``.  The
+would compile) and for an explicit ``interpret=False`` it raises
+``NotImplementedError``, pointing to ``kernel='persistent'``.  The
 parity/property/fault tests therefore exercise the real fused loop on any
 host; a compiled Mosaic lowering (explicit HBM memory spaces for the CSR
 operands, in-kernel DMA instead of the nested expansion call) is future

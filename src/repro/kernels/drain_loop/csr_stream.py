@@ -91,9 +91,9 @@ def stream_row_slices(col_idx: jax.Array, starts: jax.Array, budget: int,
     return pl.pallas_call(
         functools.partial(_stream_kernel, n_items, budget),
         out_shape=jax.ShapeDtypeStruct((n_items, budget), col_idx.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
         scratch_shapes=[pltpu.VMEM((_N_BUFFERS, budget), col_idx.dtype),
                         pltpu.SemaphoreType.DMA((_N_BUFFERS,))],
         interpret=resolve_interpret(interpret),

@@ -27,13 +27,13 @@ lowering today: the drain jaxpr contains a *nested* ``pallas_call`` (the
 arbitrary gather/scatter — none of which Mosaic can lower from inside a
 kernel body — and the operands here get default whole-array BlockSpecs,
 which contradicts HBM-resident CSR state on a real chip.  So this entry
-point ALWAYS runs through the Pallas interpreter: with ``interpret=None``
-on a TPU (where the repo-wide rule would compile) it warns and falls back
-to interpret mode, and an explicit ``interpret=False`` raises rather than
-hand Mosaic a program it cannot lower.  The launch-structure collapse and
-every correctness claim hold in interpret mode; a compiled lowering
-(explicit HBM memory spaces for the CSR operands, in-kernel DMA instead
-of the nested expansion call) is future work — see DESIGN.md §14.
+point only ever runs through the Pallas interpreter: a demand to compile
+(``interpret=None`` on a TPU, where the repo-wide rule compiles, or an
+explicit ``interpret=False``) raises rather than hand Mosaic a program it
+cannot lower or emulate the drain on the chip.  The launch-structure
+collapse and every correctness claim hold in interpret mode; a compiled
+lowering (explicit HBM memory spaces for the CSR operands, in-kernel DMA
+instead of the nested expansion call) is future work — see DESIGN.md §14.
 
 Tracing the drain is the expensive part, so it happens ONCE per
 :func:`make_fused_drain` — the returned runner reuses the jaxpr, the
@@ -42,8 +42,6 @@ with like-shaped carries (the streaming snapshot layer calls it once per
 segment).
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -60,22 +58,16 @@ _NO_LOWERING = (
 
 
 def _resolve_fused_interpret(interpret) -> bool:
-    """The megakernel's own interpret rule: ALWAYS interpret (see module
-    docstring).  ``None`` on a real TPU — where the repo-wide rule would
-    compile — warns before falling back; an explicit ``interpret=False``
-    (a demand to compile) raises."""
-    if interpret is not None and not interpret:
+    """The megakernel's own interpret rule: interpret, or raise (see module
+    docstring).  Off-TPU ``None`` resolves to the interpreter; on a real
+    TPU, where it resolves to a compile, and for an explicit
+    ``interpret=False``, this raises."""
+    if not resolve_interpret(interpret):
         raise NotImplementedError(
-            f"{_NO_LOWERING}; interpret=False cannot be honored.  Use the "
-            "default (interpret=None) to run through the Pallas "
-            "interpreter, or kernel='persistent' for a compiled "
-            "device-resident drain.")
-    if interpret is None and not resolve_interpret(None):
-        warnings.warn(
-            f"{_NO_LOWERING}; falling back to the Pallas interpreter on "
-            "this TPU.  The drain still collapses to one kernel entry, "
-            "but it runs emulated — use kernel='persistent' for compiled "
-            "TPU speed.", stacklevel=3)
+            f"{_NO_LOWERING}, so it cannot be compiled for this device.  "
+            "Use kernel='persistent' for a compiled device-resident "
+            "drain; off-TPU the megakernel runs through the Pallas "
+            "interpreter.")
     return True
 
 
@@ -90,8 +82,8 @@ def make_fused_drain(step, cond, example_carry, *, interpret=None):
     layer drives one runner through O(num_segments) calls).  ``step`` /
     ``cond`` may close over anything traceable — constants are hoisted
     into kernel operands.  ``interpret`` follows the megakernel gate
-    (:func:`_resolve_fused_interpret`): always interpret, warn on TPU,
-    reject an explicit compile request.
+    (:func:`_resolve_fused_interpret`): interpret off-TPU, raise on a TPU
+    or on an explicit compile request.
     """
     interpret = _resolve_fused_interpret(interpret)
     flat0, treedef = jax.tree.flatten(example_carry)

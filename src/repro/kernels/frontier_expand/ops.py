@@ -62,7 +62,7 @@ def frontier_expand(items, valid, row_ptr, col_idx, budget: int,
            chunk_row_of(row_ptr, head, rank, widths[owner], max_width))
     k = jnp.arange(budget, dtype=jnp.int32)
     in_range = k < total
-    edge = row_ptr[head] + rank
+    edge = row_ptr[safe][owner] + rank
     # the LBS kernel only computes (owner, rank); the gather lives out here,
     # so a slotted graph just swaps the flat read for the two-level one
     nbr = gather_neighbors(row_ptr, col_idx, src, edge, overlay=overlay)

@@ -25,19 +25,35 @@ from jax.experimental import pallas as pl
 from ...core.backend import resolve_interpret
 
 TILE = 256
+#: lanes of the per-tile count block: Mosaic tiles the last dim by 128
+LANES = 128
 
 
 def _compact_kernel(items_ref, mask_ref, out_ref, cnt_ref):
-    """items/mask: [1, TILE] -> out: [1, TILE] locally compacted, cnt: [1, 1]."""
-    items = items_ref[...].reshape(TILE)
-    mask = mask_ref[...].reshape(TILE).astype(jnp.int32)
-    pos = jnp.cumsum(mask) - mask                       # exclusive scan
-    j = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 1)
+    """items/mask: [1, TILE] -> out: [1, TILE] locally compacted,
+    cnt: [1, LANES] (the tile's count, repeated across the lanes).
+
+    Mosaic lowers no cumsum and no lane-to-sublane reshape, so both become
+    [TILE, TILE] masked lane reductions (row i = sublane, column j = lane):
+    the diagonal pick moves item j onto sublane j, the strict lower
+    triangle gives the exclusive scan, and a sublane reduction of the
+    one-hot scatters each live item into its slot.
+    """
+    items = items_ref[...]                               # [1, TILE]
+    mask = mask_ref[...]                                 # [1, TILE] 0/1
+    row = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 1)
+    eye = row == col
+    items_c = jnp.sum(jnp.where(eye, items, 0), axis=1, keepdims=True)
+    mask_c = jnp.sum(jnp.where(eye, mask, 0), axis=1, keepdims=True)
+    pos_c = jnp.sum(jnp.where(col < row, mask, 0), axis=1,
+                    keepdims=True)                       # exclusive scan
     # onehot[i, j] = item i lands in slot j
-    onehot = (pos.reshape(TILE, 1) == j) & (mask.reshape(TILE, 1) > 0)
-    compacted = jnp.sum(jnp.where(onehot, items.reshape(TILE, 1), 0), axis=0)
-    out_ref[...] = compacted.reshape(1, TILE)
-    cnt_ref[...] = jnp.sum(mask).reshape(1, 1)
+    onehot = (pos_c == col) & (mask_c > 0)
+    out_ref[...] = jnp.sum(jnp.where(onehot, items_c, 0), axis=0,
+                           keepdims=True)
+    cnt_ref[...] = jnp.broadcast_to(
+        jnp.sum(mask, axis=1, keepdims=True), (1, LANES))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -50,22 +66,22 @@ def compact_tiles_pallas(items: jax.Array, mask: jax.Array,
     items_p = jnp.zeros((1, n_pad), jnp.int32).at[0, :n].set(items)
     mask_p = jnp.zeros((1, n_pad), jnp.int32).at[0, :n].set(
         mask.astype(jnp.int32))
-    grid = (n_pad // TILE,)
+    n_tiles = n_pad // TILE
     local, counts = pl.pallas_call(
         _compact_kernel,
-        grid=grid,
+        grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((1, TILE), lambda t: (0, t)),
             pl.BlockSpec((1, TILE), lambda t: (0, t)),
         ],
         out_specs=[
             pl.BlockSpec((1, TILE), lambda t: (0, t)),
-            pl.BlockSpec((1, 1), lambda t: (0, t)),
+            pl.BlockSpec((1, LANES), lambda t: (0, t)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_pad // TILE), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_tiles * LANES), jnp.int32),
         ],
         interpret=interpret,
     )(items_p, mask_p)
-    return local.reshape(-1, TILE), counts.reshape(-1)
+    return local.reshape(-1, TILE), counts.reshape(n_tiles, LANES)[:, 0]

@@ -42,11 +42,13 @@ def compact(items: jax.Array, mask: jax.Array,
     local, counts = compact_tiles_pallas(items, mask, interpret=interpret)
     n_tiles = local.shape[0]
     tile_offs = jnp.cumsum(counts) - counts            # phase 2: global stitch
-    # element (t, j) for j < counts[t] lands at tile_offs[t] + j
+    # element (t, j) for j < counts[t] lands at tile_offs[t] + j; the rest
+    # add 0 at the next tile's first slot, so the indices stay sorted and
+    # no sort precedes the scatter
     j = jnp.arange(TILE, dtype=jnp.int32)
-    dst = tile_offs[:, None] + j[None, :]
     live = j[None, :] < counts[:, None]
-    out = jnp.zeros((n_tiles * TILE,), jnp.int32).at[
-        jnp.where(live, dst, n_tiles * TILE)
-    ].set(jnp.where(live, local, 0), mode="drop")
+    dst = tile_offs[:, None] + jnp.minimum(j[None, :], counts[:, None])
+    out = jnp.zeros((n_tiles * TILE,), jnp.int32).at[dst.reshape(-1)].add(
+        jnp.where(live, local, 0).reshape(-1), mode="drop",
+        indices_are_sorted=True)
     return out[:n], jnp.sum(counts)

@@ -23,6 +23,7 @@ from ..graph.generators import grid2d, rmat
 from ..runtime.policy import POLICY_GRID, parse_policy
 from ..server import (Autotuner, JobRegistry, JobSpec, TaskServer,
                       serve_sequential)
+from .compile_cache import use_compile_cache
 
 ALGO_CYCLE = ("bfs", "pagerank", "coloring")
 
@@ -156,7 +157,7 @@ def main() -> None:
                          "tasks, single.megakernel fuses a drain loop "
                          "into ONE Pallas kernel launch — an "
                          "interpret-mode prototype (no Mosaic lowering "
-                         "yet, so it runs emulated even on TPU), honored "
+                         "yet, so a TPU refuses it), honored "
                          "by streaming jobs' per-batch drains; the "
                          "multi-tenant server rounds themselves stay "
                          "host-driven and warn.  auto keeps the config "
@@ -256,6 +257,7 @@ def main() -> None:
     ap.add_argument("--compare-sequential", action="store_true")
     ap.add_argument("-v", "--verbose", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     logging.basicConfig(
         level=logging.INFO if args.verbose else logging.WARNING,

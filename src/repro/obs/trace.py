@@ -42,14 +42,10 @@ def default_meta() -> dict:
 
     import jax
 
-    try:
-        device_kind = jax.devices()[0].device_kind
-    except Exception:
-        device_kind = "unknown"
     return {
         "git_sha": "unknown",  # CLI entry points stamp the real sha
         "jax_version": jax.__version__,
-        "device_kind": str(device_kind),
+        "device_kind": str(jax.devices()[0].device_kind),
         "python": platform.python_version(),
     }
 

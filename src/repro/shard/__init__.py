@@ -24,7 +24,8 @@ from .driver import (ShardCounters, ShardRunStats, discrete_run_sharded,
 from .exchange import (LANE_LOCAL, LANE_STOLEN, NUM_LANES, delivered_width,
                        pop_wavefront, route_tasks)
 from .partition import (ShardedCSR, block_bounds, block_size, owner_coords,
-                        owner_of, partition_graph, split_seeds)
+                        owner_of, partition_graph, place_partition,
+                        split_seeds)
 from .steal import plan_donations, rebalance
 
 __all__ = [
@@ -33,7 +34,7 @@ __all__ = [
     "LANE_LOCAL", "LANE_STOLEN", "NUM_LANES", "delivered_width",
     "pop_wavefront", "route_tasks",
     "ShardedCSR", "block_bounds", "block_size", "owner_coords", "owner_of",
-    "partition_graph", "split_seeds",
+    "partition_graph", "place_partition", "split_seeds",
     "plan_donations", "rebalance",
     "codec_capacity", "decode_buffer", "encode_buffer",
 ]

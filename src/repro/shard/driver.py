@@ -54,7 +54,6 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.queue import EMPTY, MultiQueue, TaskQueue
@@ -65,7 +64,8 @@ from ..obs import Trace, stacked_rings, unstack_ring
 from ..runtime.program import AtosProgram, ProgramContext, build_merge
 from .exchange import (LANE_LOCAL, NUM_LANES, delivered_width, pop_wavefront,
                        route_tasks)
-from .partition import ShardedCSR, owner_of, partition_graph, split_seeds
+from .partition import (ShardedCSR, owner_of, partition_graph,
+                        place_partition, split_seeds)
 from .steal import rebalance
 
 AXIS = "shard"
@@ -243,8 +243,9 @@ def _body_out_width(program: AtosProgram, parts: ShardedCSR,
                       jnp.zeros((w,), jnp.bool_), state)
         return out
 
-    fn = shard_map(probe, mesh=mesh, in_specs=(P(axes), P(axes), P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(probe, mesh=mesh,
+                       in_specs=(P(axes), P(axes), P()), out_specs=P(),
+                       check_vma=False)
     shape = jax.eval_shape(fn, parts.row_ptr, parts.col_idx, state0)
     return shape.shape[0]
 
@@ -492,8 +493,8 @@ def persistent_run_sharded(program, parts: ShardedCSR, mq0, state0,
         in_specs = in_specs + (specs_r,)
         out_specs = out_specs + (specs_r,)
         operands = operands + (ring0,)
-    fn = shard_map(drain, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(drain, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)(*operands)
 
 
@@ -548,8 +549,8 @@ def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
         specs_r = jax.tree.map(lambda _: P(axes), ring0)
         in_specs = in_specs + (specs_r,)
         out_specs = out_specs + (specs_r,)
-    step = jax.jit(shard_map(one_round, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    step = jax.jit(jax.shard_map(one_round, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
     mq_st, state = mq0, state0
     ring_st = ring0
@@ -618,8 +619,8 @@ def _flush_pending(mq_st, pending_st, mq0, mesh, axes, backend):
         return _stacked_view(mq)
 
     specs_q = jax.tree.map(lambda _: P(axes), mq0)
-    fn = shard_map(flush, mesh=mesh, in_specs=(specs_q, P(axes)),
-                   out_specs=specs_q, check_rep=False)
+    fn = jax.shard_map(flush, mesh=mesh, in_specs=(specs_q, P(axes)),
+                       out_specs=specs_q, check_vma=False)
     return jax.jit(fn)(mq_st, pending_st)
 
 
@@ -679,6 +680,7 @@ def run_sharded(
         # per-owner patches, stream/ingest.reshard) pass it in; everyone
         # else pays the one-shot O(m) build here
         parts = partition_graph(graph, s, halo=steal_on)
+    parts = place_partition(parts, mesh)
     capacity = queue_capacity or max(4 * n, 1024)
     if initial_state is None or initial_queues is None:
         init_state, seeds = program.init()

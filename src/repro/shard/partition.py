@@ -26,6 +26,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..graph.csr import CSRGraph
 
@@ -87,6 +88,16 @@ class ShardedCSR:
         """Device-local graph view (works on traced ``shard`` too)."""
         return CSRGraph(row_ptr=self.row_ptr[shard],
                         col_idx=self.col_idx[shard])
+
+
+def place_partition(parts: ShardedCSR, mesh) -> ShardedCSR:
+    """Put shard ``d``'s CSR slice on mesh device ``d`` — the split
+    ``shard_map`` makes — instead of leaving the whole stack on the device
+    that built it.  A no-op for a partition already placed on ``mesh``."""
+    sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    return dataclasses.replace(
+        parts, row_ptr=jax.device_put(parts.row_ptr, sharding),
+        col_idx=jax.device_put(parts.col_idx, sharding))
 
 
 def partition_graph(graph: CSRGraph, num_shards: int,

@@ -10,7 +10,7 @@ from repro.algorithms.coloring import coloring_async, coloring_bsp, \
 from repro.algorithms.pagerank import pagerank_async, pagerank_bsp, \
     pagerank_reference
 from repro.core import SchedulerConfig
-from repro.graph import grid2d, permute_vertices, rmat
+from repro.graph import from_edges, grid2d, permute_vertices, rmat
 
 
 def _nx_dists(g, source):
@@ -125,6 +125,67 @@ def test_coloring_async_valid(gname, persistent, backend):
     colors, info = coloring_async(g, cfg)
     assert validate_coloring(g, colors)
     assert info["dropped"] == 0
+
+
+def test_coloring_truncated_hub_wavefront_stays_valid():
+    """Two hubs adjacent to every other vertex share the first wavefront
+    and overflow its merge-path budget (which is floored at one hub's
+    degree): the spilled hub re-queues whole, and the coloring stays
+    proper and bit-identical across backends."""
+    n = 200
+    rest = np.arange(2, n)
+    g = from_edges(n, np.repeat([0, 1], n - 2), np.tile(rest, 2),
+                   symmetrize=True)
+    out = {}
+    for backend in BACKENDS:
+        cfg = SchedulerConfig(num_workers=2, max_rounds=100000,
+                              backend=backend)
+        colors, info = coloring_async(g, cfg)
+        assert validate_coloring(g, colors), backend
+        assert info["dropped"] == 0
+        out[backend] = (np.asarray(colors), info["rounds"])
+    np.testing.assert_array_equal(out["jnp"][0], out["pallas"][0])
+    assert out["jnp"][1] == out["pallas"][1]
+
+
+def test_coloring_small_explicit_budget_stays_valid():
+    """An explicit coloring work_budget below max_degree is floored at it
+    (progress guarantee); the heavily truncated drain still colors
+    properly and agrees across backends."""
+    from repro.runtime import build_program, execute
+
+    g = GRAPHS["scale_free"]
+    out = {}
+    for backend in BACKENDS:
+        cfg = SchedulerConfig(num_workers=4, fetch_size=2,
+                              max_rounds=100000, backend=backend)
+        res = execute(build_program("coloring", g, cfg, {"work_budget": 1}),
+                      g, cfg)
+        assert validate_coloring(g, res.state.colors), backend
+        assert res.info["rounds"] < 100000 and res.info["dropped"] == 0
+        out[backend] = np.asarray(res.state.colors)
+    np.testing.assert_array_equal(out["jnp"], out["pallas"])
+
+
+@pytest.mark.parametrize("first_colors", [2, 1024])
+def test_first_free_color_matches_mex(monkeypatch, first_colors):
+    """The narrow forbidden table (and its full-palette fallback when a
+    lane has every narrow color taken) gives each lane the smallest color
+    on none of its live edges."""
+    import repro.algorithms.coloring as C
+
+    monkeypatch.setattr(C, "FIRST_COLORS", first_colors)
+    rng = np.random.default_rng(7)
+    lanes, edges, max_colors = 16, 400, 40
+    lane = rng.integers(0, lanes, edges).astype(np.int32)
+    nbr_colors = rng.integers(-1, 12, edges).astype(np.int32)
+    live = rng.random(edges) < 0.8
+    got = np.asarray(C._first_free_color(
+        jnp.asarray(lane), jnp.asarray(nbr_colors), jnp.asarray(live),
+        lanes, max_colors))
+    for i in range(lanes):
+        taken = set(nbr_colors[(lane == i) & live & (nbr_colors >= 0)])
+        assert got[i] == min(set(range(max_colors)) - taken), i
 
 
 def test_coloring_async_less_overwork_than_bsp():

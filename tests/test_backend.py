@@ -6,6 +6,7 @@ whether it runs the jnp reference or the Pallas kernels (interpret mode on
 CPU).  The queue tests here deliberately avoid hypothesis so they always run.
 """
 import dataclasses
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +37,23 @@ def test_interpret_resolution_tracks_hardware():
     assert resolve_interpret(None) == default_interpret()
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
+
+
+def test_compile_cache_placed_from_env_or_repo(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    without it the cache goes to the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.use_compile_cache() == "/elsewhere"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo_cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+    assert compile_cache.use_compile_cache() == str(repo_cache)
+    assert updates == [("jax_compilation_cache_dir", str(repo_cache))]
 
 
 def test_scheduler_config_carries_backend_axis():

@@ -298,16 +298,16 @@ def test_explicit_compile_request_is_rejected():
                            (jnp.int32(0),), interpret=False)
 
 
-def test_tpu_auto_warns_and_falls_back_to_interpret(monkeypatch):
-    """On a real TPU the repo-wide interpret rule would compile; the
-    megakernel must warn and run through the interpreter instead."""
+def test_tpu_auto_refuses_and_points_to_persistent(monkeypatch):
+    """On a real TPU the repo-wide interpret rule compiles; the megakernel
+    has no lowering, so it must raise and name the compiled strategy
+    instead of emulating the drain on the chip."""
     from repro.kernels.drain_loop import kernel as K
     monkeypatch.setattr(K, "resolve_interpret",
                         lambda i: False if i is None else bool(i))
-    with pytest.warns(UserWarning, match="interpret-mode prototype"):
-        out, = fused_drain_pallas(_count_up_step, lambda c: c[0] < 5,
-                                  (jnp.int32(0),))
-    assert int(out) == 5
+    with pytest.raises(NotImplementedError, match="kernel='persistent'"):
+        fused_drain_pallas(_count_up_step, lambda c: c[0] < 5,
+                           (jnp.int32(0),))
 
 
 def test_segment_builder_traces_once_across_limits():

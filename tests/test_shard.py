@@ -243,6 +243,14 @@ def test_multidevice_parity_and_routing():
         out['color_identical'] = bool((colors[8] == colors[1]).all()
                                       and (colors[2] == colors[1]).all())
 
+        # the drain reads each device's CSR slice where it runs: one
+        # shard of the partition on each of the 8 devices
+        from repro.launch.mesh import make_shard_mesh
+        placed = SH.place_partition(SH.partition_graph(g, 8, halo=False),
+                                    make_shard_mesh(8))
+        out['csr_devices'] = sorted(
+            s.device.id for s in placed.col_idx.addressable_shards)
+
         # pagerank: schedule differs across meshes; ranks agree within the
         # eps*deg slack of the residual formulation
         ref_pr = np.asarray(pagerank_reference(g, iters=300))
@@ -257,6 +265,7 @@ def test_multidevice_parity_and_routing():
     assert res["bfs_exchanged"][1] > 0     # 8 shards really exchanged tasks
     assert res["color_valid"] and res["color_identical"], res
     assert res["color_mis_8"] == 0
+    assert res["csr_devices"] == list(range(8)), res
     assert res["pr_err"] < 1e-4, res
     assert res["pr_mis"] == 0
 
