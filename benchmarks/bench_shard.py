@@ -52,12 +52,23 @@ STEAL_THRESHOLD = 0.5
 STEAL_CHUNK = 16
 
 
+def exchanged_per_round(trace) -> list:
+    """Tasks routed across devices in each round: the ring's per-device
+    ``exchanged`` rows summed over the devices of that round."""
+    assert trace.truncated == 0, "a drain outran the ring"
+    totals: dict = {}
+    for r in trace.records:
+        totals[r["round"]] = totals.get(r["round"], 0) + r["exchanged"]
+    return [totals[k] for k in sorted(totals)]
+
+
 def _child() -> None:
     import time
 
     import numpy as np
 
     from repro.algorithms.bfs import bfs_bsp
+    from repro.obs import Trace
     from repro.core import SchedulerConfig
     from repro.graph.generators import grid2d, rmat
     from repro import shard as SH
@@ -75,7 +86,7 @@ def _child() -> None:
             cfg = SchedulerConfig(num_workers=SHARD_WORKERS, fetch_size=1,
                                   num_shards=s, persistent=False)
             program = build_program("bfs", g, cfg, params={"source": 0})
-            trace: list = []
+            trace = Trace(capacity=1 << 16)
             t0 = time.perf_counter()
             state, stats = SH.run_sharded(program, g, cfg, trace=trace)
             wall = time.perf_counter() - t0
@@ -85,7 +96,7 @@ def _child() -> None:
                 "rounds": stats.rounds,
                 "wall_seconds": wall,
                 "exchanged_total": stats.exchanged,
-                "per_round_exchanged": [t["exchanged"] for t in trace],
+                "per_round_exchanged": exchanged_per_round(trace),
                 "per_device_items": stats.per_device_items.tolist(),
                 "occupancy_balance": stats.occupancy_balance,
                 # every round merges the int32 dist replica via pmin

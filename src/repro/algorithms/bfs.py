@@ -26,6 +26,8 @@ from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, chunk_degrees,
                     adjacency_of, chunk_seeds, coalesce_chunks,
                     expand_merge_path, expand_per_item, flatten_chunks)
 from ..graph.csr import CSRGraph
+from ..obs import Trace
+from ..obs.spans import APPLY, EXPAND, PUSH
 from ..runtime.program import AtosProgram, ProgramContext
 from ..runtime.programs import reject_unknown_params
 from .common import chunking_for, default_work_budget, max_degree_of
@@ -126,65 +128,71 @@ def make_wavefront_fn(graph: CSRGraph, strategy: str, work_budget: int,
     rp, cols, overlay = adjacency_of(graph)
 
     def f(items, valid, state: BFSState):
-        safe = jnp.where(valid, items, 0)
-        heads, widths = codec.decode(safe)
-        if strategy == "merge_path":      # CTA worker: task+data-parallel LB
-            ex = expand_merge_path(heads, valid, rp, cols,
-                                   work_budget, backend=backend,
-                                   widths=widths, max_width=g,
-                                   overlay=overlay)
-            # chunks whose rows spill past the work budget are re-queued
-            # whole (progress is guaranteed: budget >= max_degree >= any
-            # formed chunk's degree-sum, so the first popped task always
-            # expands fully).
-            deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
-            excl = jnp.cumsum(deg) - deg
-            truncated = valid & (excl + deg > work_budget)
-        else:                             # warp worker: task-parallel only
-            flat_v, flat_valid, _ = flatten_chunks(heads, widths, valid, g)
-            ex = expand_per_item(flat_v, flat_valid, rp, cols, max_degree,
-                                 overlay=overlay)
-            truncated = jnp.zeros_like(valid)
-        # edges owned by truncated chunks are excluded entirely: the chunk
-        # is re-queued whole and will relax+push on re-expansion (if we
-        # relaxed the prefix now but suppressed its pushes, the re-expansion
-        # would see "no improvement" and the neighbor would never be
-        # enqueued).  (per_item never truncates; its ex.owner indexes the
-        # flattened per-vertex lanes, so the mask below is the chunk one
-        # only on the merge_path branch.)
-        live = (ex.valid & ~truncated[ex.owner] if strategy == "merge_path"
-                else ex.valid)
-        # one-row chunks: a unit's source is its popped head, so its
-        # distance comes from the wavefront-sized table
-        src_dist = (state.dist[heads][ex.owner]
-                    if strategy == "merge_path" and g == 1
-                    else state.dist[ex.src])
-        cand = jnp.where(live, src_dist + 1, INF)
-        before = state.dist[ex.nbr]
-        tgt = jnp.where(live, ex.nbr, 0)
-        new_dist = state.dist.at[tgt].min(jnp.where(live, cand, INF),
-                                          mode="drop")
-        improved = live & (cand < before)
-        # within-wavefront dedup (beyond-paper): several lanes may improve
-        # the same neighbor; only the winning relaxation needs to requeue it.
-        # On the GPU this would need extra atomics; in the deterministic
-        # wavefront a scatter-min over lane ids is free and cuts overwork.
-        n = state.dist.shape[0]
-        lanes = jnp.arange(ex.nbr.shape[0], dtype=jnp.int32)
-        first_lane = jnp.full((n,), ex.nbr.shape[0], jnp.int32).at[
-            jnp.where(improved, ex.nbr, n)
-        ].min(jnp.where(improved, lanes, ex.nbr.shape[0]), mode="drop")
-        improved &= first_lane[ex.nbr] == lanes
-        counter = state.counter.add(jnp.sum(jnp.where(
-            valid & ~truncated, widths, 0)))
-        # push: improved (deduplicated) neighbors re-coalesce into chunks;
-        # truncated chunks are re-queued whole, unchanged.
-        out_new, new_mask, n_splits = coalesce_chunks(
-            ex.nbr, improved, codec, form_rp,
-            split_threshold=split_threshold, owner_block=owner_block)
-        counter = counter.add_splits(n_splits)
-        out_items = jnp.concatenate([out_new, jnp.where(truncated, items, 0)])
-        out_mask = jnp.concatenate([new_mask, truncated])
+        with jax.named_scope(EXPAND):
+            safe = jnp.where(valid, items, 0)
+            heads, widths = codec.decode(safe)
+            if strategy == "merge_path":  # CTA worker: task+data-parallel LB
+                ex = expand_merge_path(heads, valid, rp, cols,
+                                       work_budget, backend=backend,
+                                       widths=widths, max_width=g,
+                                       overlay=overlay)
+                # chunks whose rows spill past the work budget are re-queued
+                # whole (progress is guaranteed: budget >= max_degree >= any
+                # formed chunk's degree-sum, so the first popped task always
+                # expands fully).
+                deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
+                excl = jnp.cumsum(deg) - deg
+                truncated = valid & (excl + deg > work_budget)
+            else:                         # warp worker: task-parallel only
+                flat_v, flat_valid, _ = flatten_chunks(heads, widths, valid,
+                                                       g)
+                ex = expand_per_item(flat_v, flat_valid, rp, cols,
+                                     max_degree, overlay=overlay)
+                truncated = jnp.zeros_like(valid)
+            # edges owned by truncated chunks are excluded entirely: the
+            # chunk is re-queued whole and will relax+push on re-expansion
+            # (if we relaxed the prefix now but suppressed its pushes, the
+            # re-expansion would see "no improvement" and the neighbor would
+            # never be enqueued).  (per_item never truncates; its ex.owner
+            # indexes the flattened per-vertex lanes, so the mask below is
+            # the chunk one only on the merge_path branch.)
+            live = (ex.valid & ~truncated[ex.owner]
+                    if strategy == "merge_path" else ex.valid)
+        with jax.named_scope(APPLY):
+            # one-row chunks: a unit's source is its popped head, so its
+            # distance comes from the wavefront-sized table
+            src_dist = (state.dist[heads][ex.owner]
+                        if strategy == "merge_path" and g == 1
+                        else state.dist[ex.src])
+            cand = jnp.where(live, src_dist + 1, INF)
+            before = state.dist[ex.nbr]
+            tgt = jnp.where(live, ex.nbr, 0)
+            new_dist = state.dist.at[tgt].min(jnp.where(live, cand, INF),
+                                              mode="drop")
+            improved = live & (cand < before)
+            # within-wavefront dedup (beyond-paper): several lanes may
+            # improve the same neighbor; only the winning relaxation needs
+            # to requeue it.  On the GPU this would need extra atomics; in
+            # the deterministic wavefront a scatter-min over lane ids is
+            # free and cuts overwork.
+            n = state.dist.shape[0]
+            lanes = jnp.arange(ex.nbr.shape[0], dtype=jnp.int32)
+            first_lane = jnp.full((n,), ex.nbr.shape[0], jnp.int32).at[
+                jnp.where(improved, ex.nbr, n)
+            ].min(jnp.where(improved, lanes, ex.nbr.shape[0]), mode="drop")
+            improved &= first_lane[ex.nbr] == lanes
+            counter = state.counter.add(jnp.sum(jnp.where(
+                valid & ~truncated, widths, 0)))
+        with jax.named_scope(PUSH):
+            # improved (deduplicated) neighbors re-coalesce into chunks;
+            # truncated chunks are re-queued whole, unchanged.
+            out_new, new_mask, n_splits = coalesce_chunks(
+                ex.nbr, improved, codec, form_rp,
+                split_threshold=split_threshold, owner_block=owner_block)
+            counter = counter.add_splits(n_splits)
+            out_items = jnp.concatenate([out_new,
+                                         jnp.where(truncated, items, 0)])
+            out_mask = jnp.concatenate([new_mask, truncated])
         return out_items, out_mask, BFSState(dist=new_dist, counter=counter)
 
     return f
@@ -256,7 +264,7 @@ def bfs_speculative(
     strategy: str = "merge_path",
     work_budget: int | None = None,
     queue_capacity: int | None = None,
-    trace: list | None = None,
+    trace: Trace | None = None,
 ) -> Tuple[jax.Array, dict]:
     """Relaxed-barrier BFS on the Atos scheduler.
 
@@ -265,8 +273,8 @@ def bfs_speculative(
     policy.  ``strategy``: "merge_path" (CTA-style) or "per_item"
     (warp-style).  Under the sharded topology (``cfg.num_shards > 1`` or
     ``topology="sharded"``) distances are bit-identical to the
-    single-device run, and ``trace`` entries are per-round dicts
-    (sizes/exchanged/donated) instead of tuples.
+    single-device run.  ``trace`` (an :class:`~repro.obs.Trace`) collects
+    one row per round (per device when sharded).
     """
     from ..runtime import execute  # lazy: runtime.api imports this module
 

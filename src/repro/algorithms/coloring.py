@@ -35,6 +35,8 @@ from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, adjacency_of,
                     chunk_degrees, chunk_seeds, coalesce_chunks,
                     expand_merge_path, flatten_chunks, gather_neighbors)
 from ..graph.csr import CSRGraph
+from ..obs import Trace
+from ..obs.spans import APPLY, EXPAND, PUSH
 from ..runtime.program import AtosProgram, ProgramContext
 from ..runtime.programs import reject_unknown_params
 from .common import chunking_for, default_work_budget, max_degree_of
@@ -234,67 +236,73 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
     rp, cols, overlay = adjacency_of(graph)
 
     def f(items, valid, state: ColorState):
-        is_assign = valid & (items > 0)
-        is_detect = valid & (items < 0)
-        codes = jnp.where(is_assign, items - 1, -items - 1)
-        codes = jnp.where(valid, codes, 0)
-        heads, widths = codec.decode(codes)
-        deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
-        excl = jnp.cumsum(deg) - deg
-        truncated = valid & (excl + deg > work_budget)
-        ex = expand_merge_path(heads, valid, rp, cols, work_budget,
-                               backend=backend, widths=widths, max_width=g,
-                               overlay=overlay)
-        live = ex.valid & ~truncated[ex.owner]
-        # explode chunk tasks into their member vertices: lane kind (assign
-        # vs detect) is a chunk property, vertices are per member; each
-        # edge's lane is its source row's slot in that layout
-        vids, flat_valid, owner = flatten_chunks(heads, widths, valid, g)
-        flat_run = flat_valid & ~truncated[owner]
-        flat_assign = flat_run & is_assign[owner]
-        flat_detect = flat_run & is_detect[owner]
-        lane = ex.owner * g + (ex.src - heads[ex.owner])
-        num_lanes = vids.shape[0]
+        with jax.named_scope(EXPAND):
+            is_assign = valid & (items > 0)
+            is_detect = valid & (items < 0)
+            codes = jnp.where(is_assign, items - 1, -items - 1)
+            codes = jnp.where(valid, codes, 0)
+            heads, widths = codec.decode(codes)
+            deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
+            excl = jnp.cumsum(deg) - deg
+            truncated = valid & (excl + deg > work_budget)
+            ex = expand_merge_path(heads, valid, rp, cols, work_budget,
+                                   backend=backend, widths=widths,
+                                   max_width=g, overlay=overlay)
+            live = ex.valid & ~truncated[ex.owner]
+            # explode chunk tasks into their member vertices: lane kind
+            # (assign vs detect) is a chunk property, vertices are per
+            # member; each edge's lane is its source row's slot in that
+            # layout
+            vids, flat_valid, owner = flatten_chunks(heads, widths, valid, g)
+            flat_run = flat_valid & ~truncated[owner]
+            flat_assign = flat_run & is_assign[owner]
+            flat_detect = flat_run & is_detect[owner]
+            lane = ex.owner * g + (ex.src - heads[ex.owner])
+            num_lanes = vids.shape[0]
 
-        # ---- phase A: assigns (all reads see pre-wavefront colors = stale
-        # speculation, exactly the GPU race the paper analyzes)
-        pick = _first_free_color(lane, state.colors[ex.nbr],
-                                 live & is_assign[ex.owner], num_lanes,
-                                 max_colors)
-        # duplicate assign tasks for one vertex cannot exist (1 assign ->
-        # 1 detect -> at most 1 re-assign, and chunk members are distinct),
-        # so this scatter has unique targets
-        colors = state.colors.at[jnp.where(flat_assign, vids, n)].set(
-            jnp.where(flat_assign, pick, 0), mode="drop")
+        with jax.named_scope(APPLY):
+            # ---- phase A: assigns (all reads see pre-wavefront colors =
+            # stale speculation, exactly the GPU race the paper analyzes)
+            pick = _first_free_color(lane, state.colors[ex.nbr],
+                                     live & is_assign[ex.owner], num_lanes,
+                                     max_colors)
+            # duplicate assign tasks for one vertex cannot exist (1 assign
+            # -> 1 detect -> at most 1 re-assign, and chunk members are
+            # distinct), so this scatter has unique targets
+            colors = state.colors.at[jnp.where(flat_assign, vids, n)].set(
+                jnp.where(flat_assign, pick, 0), mode="drop")
 
-        # ---- phase B: detects run on post-assign colors of THIS wavefront
-        # (uberkernel fusion: later tasks see earlier tasks' commits).  The
-        # unfused variant reads epoch-start colors so detection is identical
-        # no matter which device processed the wavefront (shard parity).
-        detect_colors = colors if fused else state.colors
-        my = (detect_colors[heads][ex.owner] if g == 1
-              else detect_colors[ex.src])
-        clash = (live & is_detect[ex.owner] & (my >= 0)
-                 & (detect_colors[ex.nbr] == my) & _loses(ex.src, ex.nbr))
-        bad = jnp.zeros((num_lanes,), jnp.bool_).at[
-            jnp.where(clash, lane, num_lanes)].set(True, mode="drop")
-        bad &= flat_detect
+            # ---- phase B: detects run on post-assign colors of THIS
+            # wavefront (uberkernel fusion: later tasks see earlier tasks'
+            # commits).  The unfused variant reads epoch-start colors so
+            # detection is identical no matter which device processed the
+            # wavefront (shard parity).
+            detect_colors = colors if fused else state.colors
+            my = (detect_colors[heads][ex.owner] if g == 1
+                  else detect_colors[ex.src])
+            clash = (live & is_detect[ex.owner] & (my >= 0)
+                     & (detect_colors[ex.nbr] == my) & _loses(ex.src, ex.nbr))
+            bad = jnp.zeros((num_lanes,), jnp.bool_).at[
+                jnp.where(clash, lane, num_lanes)].set(True, mode="drop")
+            bad &= flat_detect
+            counter = state.counter.add(jnp.sum(flat_assign.astype(
+                jnp.int32)))
 
-        # conflicted vertices re-coalesce into assign chunks (identity at
-        # G = 1: each bad vertex re-assigns alone, exactly the old stream);
-        # truncated chunks are re-queued whole, unchanged.
-        re_assign, re_mask, n_splits = coalesce_chunks(
-            vids, bad, codec, form_rp, split_threshold=split_threshold,
-            owner_block=owner_block)
-        done_assign = is_assign & ~truncated
-        out = jnp.concatenate([
-            jnp.where(done_assign, -(codes + 1), 0),  # assign -> a detect
-            jnp.where(re_mask, re_assign + 1, 0),     # conflict -> re-assign
-            jnp.where(truncated, items, 0),
-        ])
-        mask = jnp.concatenate([done_assign, re_mask, truncated])
-        counter = state.counter.add(jnp.sum(flat_assign.astype(jnp.int32)))
-        counter = counter.add_splits(n_splits)
+        with jax.named_scope(PUSH):
+            # conflicted vertices re-coalesce into assign chunks (identity
+            # at G = 1: each bad vertex re-assigns alone, exactly the old
+            # stream); truncated chunks are re-queued whole, unchanged.
+            re_assign, re_mask, n_splits = coalesce_chunks(
+                vids, bad, codec, form_rp, split_threshold=split_threshold,
+                owner_block=owner_block)
+            done_assign = is_assign & ~truncated
+            out = jnp.concatenate([
+                jnp.where(done_assign, -(codes + 1), 0),  # assign -> detect
+                jnp.where(re_mask, re_assign + 1, 0),     # conflict -> again
+                jnp.where(truncated, items, 0),
+            ])
+            mask = jnp.concatenate([done_assign, re_mask, truncated])
+            counter = counter.add_splits(n_splits)
         return out, mask, ColorState(colors=colors, counter=counter)
 
     return f
@@ -374,7 +382,7 @@ def coloring_async(
     graph: CSRGraph,
     cfg: SchedulerConfig,
     queue_capacity: int | None = None,
-    trace: list | None = None,
+    trace: Trace | None = None,
 ) -> Tuple[jax.Array, dict]:
     """Alg 6: fused assign/detect uberkernel on the Atos queue.
 

@@ -31,6 +31,8 @@ from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, adjacency_of,
                     chunk_degrees, chunk_seeds, coalesce_chunks,
                     expand_merge_path, flatten_chunks)
 from ..graph.csr import CSRGraph
+from ..obs import Trace
+from ..obs.spans import APPLY, EXPAND, PUSH
 from ..runtime.program import AtosProgram, ProgramContext
 from ..runtime.programs import reject_unknown_params
 from .common import chunking_for, default_work_budget, max_degree_of
@@ -71,62 +73,70 @@ def _push_wavefront(graph: CSRGraph, damping: float, work_budget: int,
     def push(items, valid, state: PRState):
         n = state.rank.shape[0]
         k = items.shape[0]
-        safe = jnp.where(valid, items, 0)
-        heads, widths = codec.decode(safe)
-        # de-duplicate within the wavefront (atomicExch semantics): keep the
-        # first occurrence of each chunk head.  Chunks never overlap (the
-        # presence bit gates every enqueue per vertex), so head identity is
-        # chunk identity.
-        order = jnp.arange(k, dtype=jnp.int32)
-        first_idx = jnp.full((n,), k, jnp.int32)
-        first_idx = first_idx.at[heads].min(jnp.where(valid, order, k),
-                                            mode="drop")
-        is_first = valid & (first_idx[heads] == order)
+        with jax.named_scope(EXPAND):
+            safe = jnp.where(valid, items, 0)
+            heads, widths = codec.decode(safe)
+            # de-duplicate within the wavefront (atomicExch semantics): keep
+            # the first occurrence of each chunk head.  Chunks never overlap
+            # (the presence bit gates every enqueue per vertex), so head
+            # identity is chunk identity.
+            order = jnp.arange(k, dtype=jnp.int32)
+            first_idx = jnp.full((n,), k, jnp.int32)
+            first_idx = first_idx.at[heads].min(jnp.where(valid, order, k),
+                                                mode="drop")
+            is_first = valid & (first_idx[heads] == order)
 
-        # chunks spilling past the work budget are not harvested; they are
-        # re-queued whole (same discipline as speculative BFS; formation
-        # caps every chunk's degree-sum at the budget, so the first popped
-        # task always expands fully).
-        deg = chunk_degrees(heads, widths, is_first, graph.row_ptr)
-        excl = jnp.cumsum(deg) - deg
-        truncated = is_first & (excl + deg > work_budget)
-        process = is_first & ~truncated
+            # chunks spilling past the work budget are not harvested; they
+            # are re-queued whole (same discipline as speculative BFS;
+            # formation caps every chunk's degree-sum at the budget, so the
+            # first popped task always expands fully).
+            deg = chunk_degrees(heads, widths, is_first, graph.row_ptr)
+            excl = jnp.cumsum(deg) - deg
+            truncated = is_first & (excl + deg > work_budget)
+            process = is_first & ~truncated
 
-        # harvest: dense mask avoids duplicate-index scatter hazards
-        flat_v, flat_valid, flat_owner = flatten_chunks(heads, widths,
-                                                        valid, g)
-        proc_flat = flat_valid & process[flat_owner]
-        popped = jnp.zeros((n,), bool).at[
-            jnp.where(proc_flat, flat_v, n)
-        ].set(True, mode="drop")
-        rank = state.rank + jnp.where(popped, state.residue, 0.0)
-        residue = jnp.where(popped, 0.0, state.residue)
-        # popped vertices leave the queue; truncated ones stay (re-queued)
-        trunc_flat = flat_valid & truncated[flat_owner]
-        trunc_mask = jnp.zeros((n,), bool).at[
-            jnp.where(trunc_flat, flat_v, n)
-        ].set(True, mode="drop")
-        in_queue = jnp.where(popped & ~trunc_mask, False, state.in_queue)
+        with jax.named_scope(APPLY):
+            # harvest: dense mask avoids duplicate-index scatter hazards
+            flat_v, flat_valid, flat_owner = flatten_chunks(heads, widths,
+                                                            valid, g)
+            proc_flat = flat_valid & process[flat_owner]
+            popped = jnp.zeros((n,), bool).at[
+                jnp.where(proc_flat, flat_v, n)
+            ].set(True, mode="drop")
+            rank = state.rank + jnp.where(popped, state.residue, 0.0)
+            residue = jnp.where(popped, 0.0, state.residue)
+            # popped vertices leave the queue; truncated ones stay
+            trunc_flat = flat_valid & truncated[flat_owner]
+            trunc_mask = jnp.zeros((n,), bool).at[
+                jnp.where(trunc_flat, flat_v, n)
+            ].set(True, mode="drop")
+            in_queue = jnp.where(popped & ~trunc_mask, False, state.in_queue)
 
-        rp, cols, overlay = adjacency_of(graph)
-        ex = expand_merge_path(heads, process, rp, cols,
-                               work_budget, backend=backend,
-                               widths=widths, max_width=g, overlay=overlay)
-        # per-edge contribution from the edge's true source row: ex.src is
-        # the chunk member owning the edge, its residue read pre-harvest.
-        # One-row chunks share it per popped task: computed on the
-        # wavefront, then read per edge from that [k] table.
-        src = heads if g == 1 else ex.src
-        row_deg = jnp.maximum(
-            graph.row_ptr[src + 1] - graph.row_ptr[src], 1
-        ).astype(jnp.float32)
-        res_src = jnp.where(popped[src], state.residue[src], 0.0)
-        contrib = damping * res_src / row_deg
-        contrib = jnp.where(ex.valid,
-                            contrib[ex.owner] if g == 1 else contrib, 0.0)
-        residue = residue.at[jnp.where(ex.valid, ex.nbr, 0)].add(contrib,
-                                                                 mode="drop")
-        counter = state.counter.add(jnp.sum(jnp.where(process, widths, 0)))
+        with jax.named_scope(EXPAND):
+            rp, cols, overlay = adjacency_of(graph)
+            ex = expand_merge_path(heads, process, rp, cols,
+                                   work_budget, backend=backend,
+                                   widths=widths, max_width=g,
+                                   overlay=overlay)
+        with jax.named_scope(APPLY):
+            # per-edge contribution from the edge's true source row: ex.src
+            # is the chunk member owning the edge, its residue read
+            # pre-harvest.  One-row chunks share it per popped task:
+            # computed on the wavefront, then read per edge from that [k]
+            # table.
+            src = heads if g == 1 else ex.src
+            row_deg = jnp.maximum(
+                graph.row_ptr[src + 1] - graph.row_ptr[src], 1
+            ).astype(jnp.float32)
+            res_src = jnp.where(popped[src], state.residue[src], 0.0)
+            contrib = damping * res_src / row_deg
+            contrib = jnp.where(ex.valid,
+                                contrib[ex.owner] if g == 1 else contrib,
+                                0.0)
+            residue = residue.at[jnp.where(ex.valid, ex.nbr, 0)].add(
+                contrib, mode="drop")
+            counter = state.counter.add(jnp.sum(jnp.where(process, widths,
+                                                          0)))
         return residue, rank, in_queue, counter, truncated
 
     return push
@@ -278,20 +288,24 @@ def make_wavefront_fns(
 
     def f(items, valid, state: PRState):
         residue, rank, in_queue, counter, truncated = push(items, valid, state)
-        # rotating residual re-scan (Alg 4 lines 11-14): each wavefront checks
-        # the next n_check vertices and enqueues those above eps that are not
-        # already queued (presence bit — see adaptation note above).
-        check_ids, in_window = scan_window(state.check_cursor)
-        over = in_window & (residue[check_ids] > eps) & ~in_queue[check_ids]
-        in_queue = in_queue.at[jnp.where(over, check_ids, n)].set(
-            True, mode="drop")
-        out_scan, scan_mask, n_splits = chunk_window(check_ids, over)
-        counter = counter.add_splits(n_splits)
+        with jax.named_scope(APPLY):
+            # rotating residual re-scan (Alg 4 lines 11-14): each wavefront
+            # checks the next n_check vertices and enqueues those above eps
+            # that are not already queued (presence bit — see adaptation
+            # note above).
+            check_ids, in_window = scan_window(state.check_cursor)
+            over = (in_window & (residue[check_ids] > eps)
+                    & ~in_queue[check_ids])
+            in_queue = in_queue.at[jnp.where(over, check_ids, n)].set(
+                True, mode="drop")
+        with jax.named_scope(PUSH):
+            out_scan, scan_mask, n_splits = chunk_window(check_ids, over)
+            counter = counter.add_splits(n_splits)
+            out = jnp.concatenate([out_scan, jnp.where(truncated, items, 0)])
+            mask = jnp.concatenate([scan_mask, truncated])
         new_state = PRState(rank=rank, residue=residue, in_queue=in_queue,
                             check_cursor=state.check_cursor + n_check,
                             counter=counter)
-        out = jnp.concatenate([out_scan, jnp.where(truncated, items, 0)])
-        mask = jnp.concatenate([scan_mask, truncated])
         return out, mask, new_state
 
     def on_empty(state: PRState):
@@ -429,7 +443,7 @@ def pagerank_async(
     check_size: int = 64,
     work_budget: int | None = None,
     queue_capacity: int | None = None,
-    trace: list | None = None,
+    trace: Trace | None = None,
 ) -> Tuple[jax.Array, dict]:
     """Alg 4: queue-driven asynchronous PageRank on the Atos scheduler.
 

@@ -45,6 +45,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.spans import ON_EMPTY, POP, PUSH
 from .counters import WorkCounter
 from .queue import TaskQueue
 
@@ -187,26 +188,36 @@ def wavefront_step(f: WavefrontFn, on_empty, ops: QueueOps, carry,
     zero-valid wavefront (the sharded engine's mode: a rescan folded into
     ``f`` must advance even on a drained replica, and SPMD lockstep forbids
     data-dependent branching across devices anyway).
+
+    Each phase runs under its device scope (``repro.obs.spans``): the pop
+    under ``atos.pop``, the push under ``atos.push``, the empty branch under
+    ``atos.on_empty``; ``f`` names its own expand / apply / push phases.
     """
     queue, state, rounds, processed = carry
-    items, valid, queue = ops.pop(queue)
-    n_valid = jnp.sum(valid.astype(jnp.int32))
+    with jax.named_scope(POP):
+        items, valid, queue = ops.pop(queue)
+        n_valid = jnp.sum(valid.astype(jnp.int32))
+
+    def push(q, out, mask):
+        with jax.named_scope(PUSH):
+            return ops.push(q, out, mask)
 
     if always_run_body:
         out, mask, state = f(items, valid, state)
-        queue = ops.push(queue, out, mask)
+        queue = push(queue, out, mask)
     else:
         def run_f(args):
             q, s = args
             out, mask, s2 = f(items, valid, s)
-            return ops.push(q, out, mask), s2
+            return push(q, out, mask), s2
 
         def run_empty(args):
             q, s = args
             if on_empty is None:
                 return q, s
-            out, mask, s2 = on_empty(s)
-            return ops.push(q, out, mask), s2
+            with jax.named_scope(ON_EMPTY):
+                out, mask, s2 = on_empty(s)
+            return push(q, out, mask), s2
 
         queue, state = jax.lax.cond(n_valid > 0, run_f, run_empty,
                                     (queue, state))
@@ -283,16 +294,15 @@ def megakernel_segment(step, cond, example_carry, *, interpret=None):
                                    interpret=interpret)
 
 
-def discrete_drive(step, cond, ops: QueueOps, carry0, trace=None):
+def discrete_drive(step, cond, carry0):
     """Host loop, one jitted round per iteration (discrete kernels).
 
     The continuation predicate — including any ``stop(state)`` — is
     evaluated *inside* the jitted step, so each round costs exactly one
     scalar device->host sync (the flag) instead of a full ``stop``
-    evaluation + retrace hazard on the host.  ``trace``, if given, collects
-    per-round ``(queue_size_before_pop, items_processed)`` pairs — this
-    powers the throughput-timeline benchmark (paper Figs 1-3) at the price
-    of extra host syncs, which is why it is opt-in.
+    evaluation + retrace hazard on the host.  Per-round telemetry comes
+    from the obs ring (``runtime.execute(..., trace=obs.Trace())``), which
+    adds no host sync.
     """
 
     @jax.jit
@@ -304,13 +314,8 @@ def discrete_drive(step, cond, ops: QueueOps, carry0, trace=None):
     # cond on concrete arrays evaluates eagerly — the pre-loop check costs
     # one tiny dispatch, never a per-round one.
     more = bool(cond(carry0))
-    prev_processed = 0
     while more:
-        size_before = int(ops.size(carry[0])) if trace is not None else 0
         carry, more_dev = round_step(carry)
-        if trace is not None:
-            trace.append((size_before, int(carry[3]) - prev_processed))
-            prev_processed = int(carry[3])
         more = bool(more_dev)  # the one per-round device->host sync
     return carry
 
@@ -343,7 +348,6 @@ def discrete_run(
     stop: Optional[Callable[[Any], jax.Array]] = None,
     on_empty=None,
     empty_means_done: Optional[bool] = None,
-    trace: Optional[list] = None,
 ):
     """Host-driven loop: one jitted wavefront per round (discrete kernels)."""
     ops = taskqueue_ops(cfg)
@@ -351,8 +355,7 @@ def discrete_run(
                         resolve_empty_means_done(on_empty, empty_means_done))
     step = lambda carry: wavefront_step(f, on_empty, ops, carry)
     q, s, rounds, processed = discrete_drive(
-        step, cond, ops, (queue, state, jnp.int32(0), jnp.int32(0)),
-        trace=trace)
+        step, cond, (queue, state, jnp.int32(0), jnp.int32(0)))
     return q, s, RunStats(rounds, processed, q.dropped)
 
 
@@ -385,7 +388,7 @@ def megakernel_run(
 
 
 def run(f, queue, state, cfg: SchedulerConfig, stop=None, on_empty=None,
-        empty_means_done: Optional[bool] = None, trace=None):
+        empty_means_done: Optional[bool] = None):
     """Dispatch on the kernel strategy — the Atos ``ifPersist`` switch,
     three-valued since the megakernel: an explicit
     ``cfg.kernel="megakernel"`` routes to :func:`megakernel_run` (the
@@ -405,7 +408,7 @@ def run(f, queue, state, cfg: SchedulerConfig, stop=None, on_empty=None,
                               on_empty=on_empty,
                               empty_means_done=empty_means_done)
     return discrete_run(f, queue, state, cfg, stop=stop, on_empty=on_empty,
-                        empty_means_done=empty_means_done, trace=trace)
+                        empty_means_done=empty_means_done)
 
 
 # ------------------------------------------------------- deprecated aliases

@@ -13,9 +13,12 @@ One schema, one collector, two exporters for every engine in the repo:
   * :mod:`~repro.obs.export` — atomic JSONL + Chrome-trace writers;
   * :mod:`~repro.obs.trace`  — the :class:`Trace` front door wired through
     ``runtime.execute(..., trace=...)``, the task server, the stream
-    driver, and ``taskserver --trace-out/--metrics-out``.
+    driver, and ``taskserver --trace-out/--metrics-out``;
+  * :mod:`~repro.obs.spans`  — the always-on names on the profiler's
+    clock: ``atos:<step>`` host spans (:func:`span`) and the ``atos.<phase>``
+    device scopes of each scheduling round.
 
-Tracing is strictly opt-in: every entry point takes ``trace=None`` by
+The ring is strictly opt-in: every entry point takes ``trace=None`` by
 default and builds exactly the pre-observability computation when it is
 absent — the disabled path is the identity, proven bit-for-bit by
 tests/test_obs.py across all six policies plus the megakernel.
@@ -29,6 +32,7 @@ from .schema import (BENCH_META_KEYS, KINDS, NUM_FIELDS, SCHEMA_VERSION,
                      TRACE_FIELDS, metric_doc, validate_bench,
                      validate_chrome_trace, validate_metric,
                      validate_metrics_jsonl)
+from .spans import SPAN_PREFIX, span
 from .trace import Trace, default_meta
 
 __all__ = [
@@ -37,5 +41,6 @@ __all__ = [
     "ring_rows", "stacked_rings", "unstack_ring", "BENCH_META_KEYS",
     "KINDS", "NUM_FIELDS", "SCHEMA_VERSION", "TRACE_FIELDS", "metric_doc",
     "validate_bench", "validate_chrome_trace", "validate_metric",
-    "validate_metrics_jsonl", "Trace", "default_meta",
+    "validate_metrics_jsonl", "SPAN_PREFIX", "span", "Trace",
+    "default_meta",
 ]

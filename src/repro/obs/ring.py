@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .schema import NUM_FIELDS, TRACE_FIELDS
+from .spans import RING
 
 _FIELD_INDEX = {name: i for i, name in enumerate(TRACE_FIELDS)}
 
@@ -65,13 +66,14 @@ class TraceRing:
             raise ValueError(
                 f"unknown trace fields {sorted(unknown)}; the row layout is "
                 f"{TRACE_FIELDS} (obs/schema.TRACE_FIELDS)")
-        row = jnp.zeros((NUM_FIELDS,), jnp.int32)
-        for name, value in fields.items():
-            row = row.at[_FIELD_INDEX[name]].set(
-                jnp.asarray(value, jnp.int32))
-        idx = jnp.mod(self.cursor, self.buf.shape[0])
-        return TraceRing(buf=self.buf.at[idx].set(row),
-                         cursor=self.cursor + 1)
+        with jax.named_scope(RING):
+            row = jnp.zeros((NUM_FIELDS,), jnp.int32)
+            for name, value in fields.items():
+                row = row.at[_FIELD_INDEX[name]].set(
+                    jnp.asarray(value, jnp.int32))
+            idx = jnp.mod(self.cursor, self.buf.shape[0])
+            return TraceRing(buf=self.buf.at[idx].set(row),
+                             cursor=self.cursor + 1)
 
 
 def ring_rows(ring: TraceRing) -> Tuple[List[dict], int]:
@@ -108,5 +110,7 @@ def stacked_rings(ring: TraceRing, count: int) -> TraceRing:
 
 
 def unstack_ring(ring_st: TraceRing, device: int) -> TraceRing:
-    """One device's ring out of a stacked pytree (host side, post-drain)."""
-    return jax.tree.map(lambda x: x[device], ring_st)
+    """One device's ring out of a stacked pytree (host side, post-drain:
+    the slice is taken on the host, so a mesh of fewer devices than the
+    host has needs no gather sharding)."""
+    return jax.tree.map(lambda x: np.asarray(x)[device], ring_st)

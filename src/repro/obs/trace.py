@@ -7,8 +7,9 @@ A ``Trace`` ties the layer together (DESIGN.md §15):
     engine name so one trace can hold a whole multi-engine session
     (server rounds + sharded phases + stream segments side by side);
   * records host wall-clock **spans** (:meth:`Trace.span` context
-    manager) on a shared epoch, so trace/compile/execute phases line up
-    in the exported timeline;
+    manager) on a shared epoch, so the steps of ``execute`` line up in the
+    exported timeline; each span is also a ``jax.profiler`` annotation of
+    the same name (:mod:`~repro.obs.spans`);
   * owns a **metrics registry** (:meth:`Trace.add_metric`): every
     end-of-run summary doc the engines serialize (run / shard_run /
     server / stream / job kinds) validated against ``obs/schema`` at
@@ -26,13 +27,13 @@ bit-for-bit across every policy).
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 from .export import chrome_trace, write_chrome_trace, write_jsonl
 from .hist import LatencyHistogram
 from .ring import DEFAULT_CAPACITY, TraceRing, ring_rows
 from .schema import SCHEMA_VERSION, metric_doc, validate_metric
+from .spans import span
 
 
 def default_meta() -> dict:
@@ -90,18 +91,17 @@ class Trace:
         return len(rows)
 
     # --------------------------------------------------------------- host
-    @contextmanager
     def span(self, name: str):
-        """Record one host wall-clock span on the trace's shared epoch."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self.spans.append(metric_doc(
-                "span", name=name,
-                ts_us=(t0 - self._epoch) * 1e6,
-                dur_us=(t1 - t0) * 1e6))
+        """Record one host wall-clock span on the trace's shared epoch; the
+        same call opens the profiler annotation ``atos:<name>``
+        (:func:`~repro.obs.spans.span`)."""
+        return span(name, self)
+
+    def add_span(self, name: str, t0: float, t1: float) -> None:
+        """The span doc of ``[t0, t1]`` (``time.perf_counter`` seconds)."""
+        self.spans.append(metric_doc(
+            "span", name=name, ts_us=(t0 - self._epoch) * 1e6,
+            dur_us=(t1 - t0) * 1e6))
 
     def histogram(self, name: str) -> LatencyHistogram:
         """Get-or-create a named latency histogram."""

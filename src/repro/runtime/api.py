@@ -10,10 +10,10 @@ drivers, the autotuner, benchmarks, tests) never branch on topology.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..core.backend import STREAM
@@ -21,7 +21,8 @@ from ..core.queue import make_multiqueue, make_queue
 from ..core.scheduler import (QueueOps, RunStats, SchedulerConfig,
                               continuation, discrete_drive, megakernel_drive,
                               persistent_drive, taskqueue_ops, wavefront_step)
-from ..obs import Trace
+from ..obs import Trace, span
+from ..obs.spans import RING
 from .policy import ExecutionPolicy, policy_of
 from .program import AtosProgram, ProgramContext
 
@@ -146,7 +147,8 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
     size, pops, pushes, per-round work/split deltas) with pure in-trace
     ``.at[]`` writes — zero host syncs; the wrapped ``cond`` simply strips
     the ring.  Work/splits deltas come from ``program.work``/``.splits``
-    when declared (traced scalars), else 0.
+    when declared (traced scalars), else 0.  The bookkeeping runs under the
+    ``atos.ring`` device scope.
     """
     work_of = program.work if program is not None else None
     splits_of = program.splits if program is not None else None
@@ -154,16 +156,18 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
     def traced_step(carry):
         *inner, ring = carry
         q0, s0, r0, p0 = inner
-        size_before = ops.size(q0)
+        with jax.named_scope(RING):
+            size_before = ops.size(q0)
         q1, s1, r1, p1 = step((q0, s0, r0, p0))
-        pops = p1 - p0
-        ring = ring.record(
-            round=r0, lane=lane, queue_size=size_before, pops=pops,
-            pushes=ops.size(q1) - size_before + pops,
-            work=(work_of(s1) - work_of(s0)) if work_of is not None else 0,
-            splits=(splits_of(s1) - splits_of(s0))
-                   if splits_of is not None else 0,
-            donated=0, exchanged=0)
+        with jax.named_scope(RING):
+            pops = p1 - p0
+            work = work_of(s1) - work_of(s0) if work_of is not None else 0
+            splits = (splits_of(s1) - splits_of(s0)
+                      if splits_of is not None else 0)
+            ring = ring.record(
+                round=r0, lane=lane, queue_size=size_before, pops=pops,
+                pushes=ops.size(q1) - size_before + pops, work=work,
+                splits=splits, donated=0, exchanged=0)
         return q1, s1, r1, p1, ring
 
     def traced_cond(carry):
@@ -174,45 +178,47 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
 
 def _run_shared_core(program: AtosProgram, graph, cfg: SchedulerConfig,
                      policy: ExecutionPolicy, queue_capacity: Optional[int],
-                     trace):
-    """single / fused topologies: same step core, different QueueOps."""
-    obs = trace if isinstance(trace, Trace) else None
-    legacy = trace if isinstance(trace, list) else None
-    queue, state, ops, step, cond, dropped_of = _shared_setup(
-        program, graph, cfg, policy, queue_capacity)
-    carry0 = (queue, state, jnp.int32(0), jnp.int32(0))
-    ring = None
-    if obs is not None:
-        # tracing on: identical drain with the ring as one extra carry leaf
-        step, cond = instrument_step(step, cond, ops, program)
-        carry0 = carry0 + (obs.ring(),)
-    span = (obs.span(f"execute {policy}") if obs is not None
-            else contextlib.nullcontext())
-    with span:
+                     obs: Optional[Trace]):
+    """single / fused topologies: same step core, different QueueOps.
+
+    Each host step runs under its ``atos:`` span (``repro.obs.spans``):
+    ``init`` (program init, seed placement, body construction — eager
+    dispatches), ``drain`` (re-trace of the loop body, lowering, compile or
+    cache load, dispatch) and ``readback`` (the host's waits on the
+    device's counters)."""
+    with span("init", obs):
+        queue, state, ops, step, cond, dropped_of = _shared_setup(
+            program, graph, cfg, policy, queue_capacity)
+        carry0 = (queue, state, jnp.int32(0), jnp.int32(0))
+        if obs is not None:
+            # tracing on: identical drain with the ring as one extra leaf
+            step, cond = instrument_step(step, cond, ops, program)
+            carry0 = carry0 + (obs.ring(),)
+    with span("drain", obs):
         if policy.kernel == "megakernel":
             carry = megakernel_drive(step, cond, carry0)
         elif policy.persistent:
             carry = persistent_drive(step, cond, carry0)
         else:
-            carry = discrete_drive(step, cond, ops, carry0, trace=legacy)
-    queue, state, rounds, processed = carry[:4]
-    if obs is not None:
-        ring = carry[4]
-    stats = RunStats(rounds, processed, dropped_of(queue))
-    info = {
-        "rounds": int(stats.rounds),
-        "work": program.work_of(state),
-        "dropped": int(stats.dropped),
-        "splits": program.splits_of(state),
-        # kernel-entry events per drain: persistent/discrete re-enter the
-        # expand/push kernels every round (one host dispatch per round for
-        # discrete; one while-loop iteration per round for persistent);
-        # the megakernel is ONE launch for the whole drain (DESIGN.md §14)
-        "launches": 1 if policy.kernel == "megakernel" else int(rounds),
-    }
-    if obs is not None:
-        obs.drain(ring, engine=str(policy))
-        obs.add_metric(run_doc(policy, stats, info))
+            carry = discrete_drive(step, cond, carry0)
+    with span("readback", obs):
+        queue, state, rounds, processed = carry[:4]
+        stats = RunStats(rounds, processed, dropped_of(queue))
+        info = {
+            "rounds": int(stats.rounds),
+            "work": program.work_of(state),
+            "dropped": int(stats.dropped),
+            "splits": program.splits_of(state),
+            # kernel-entry events per drain: persistent/discrete re-enter
+            # the expand/push kernels every round (one host dispatch per
+            # round for discrete; one while-loop iteration per round for
+            # persistent); the megakernel is ONE launch for the whole
+            # drain (DESIGN.md §14)
+            "launches": 1 if policy.kernel == "megakernel" else int(rounds),
+        }
+        if obs is not None:
+            obs.drain(carry[4], engine=str(policy))
+            obs.add_metric(run_doc(policy, stats, info))
     return ExecutionResult(state, stats, info)
 
 
@@ -232,9 +238,10 @@ def _run_sharded(program: AtosProgram, graph, cfg: SchedulerConfig,
                  queue_capacity, trace, route_width, mesh):
     from .. import shard as _shard  # lazy: shard imports this package
 
-    state, sstats = _shard.run_sharded(
-        program, graph, cfg, queue_capacity=queue_capacity,
-        route_width=route_width, mesh=mesh, trace=trace)
+    with span("drain", trace):
+        state, sstats = _shard.run_sharded(
+            program, graph, cfg, queue_capacity=queue_capacity,
+            route_width=route_width, mesh=mesh, trace=trace)
     stats = RunStats(jnp.int32(sstats.rounds),
                      jnp.int32(sstats.items_processed),
                      jnp.int32(sstats.dropped + sstats.route_dropped))
@@ -269,7 +276,7 @@ def execute(
     cfg: SchedulerConfig,
     *,
     queue_capacity: Optional[int] = None,
-    trace: Optional[Any] = None,
+    trace: Optional[Trace] = None,
     route_width: Optional[int] = None,
     mesh=None,
 ) -> ExecutionResult:
@@ -279,15 +286,18 @@ def execute(
     per-topology telemetry (exchange/steal meters for sharded runs; for
     single/fused runs ``info["launches"]`` counts kernel-entry events per
     drain — O(rounds) for persistent/discrete, 1 for the megakernel).
-    ``trace`` accepts either an :class:`~repro.obs.Trace` — the unified
+    ``trace`` takes an :class:`~repro.obs.Trace` — the unified
     observability collector (DESIGN.md §15): a device-side ring buffer rides
     the drain carry under *every* policy, recording one structured row per
     round with zero host syncs, drained into the collector at run end
-    alongside a canonical run-summary doc — or, for backward compatibility,
-    a plain ``list``, honored by the discrete kernel strategy only
-    (per-round ``(size, items)`` tuples).  ``trace=None`` (default) builds
-    exactly the untraced computation — no ring, no wrapped step.
+    alongside a canonical run-summary doc.  ``trace=None`` (default) builds
+    exactly the untraced computation — no ring, no wrapped step.  Either
+    way the host steps carry ``atos:`` profiler spans and the round phases
+    ``atos.*`` device scopes (``repro.obs.spans``).
     """
+    if trace is not None and not isinstance(trace, Trace):
+        raise TypeError(f"trace takes a repro.obs.Trace (its per-round rows "
+                        f"are trace.records), not {type(trace).__name__}")
     policy = policy_of(cfg)
     if policy.topology == "sharded":
         return _run_sharded(program, graph, cfg, queue_capacity, trace,

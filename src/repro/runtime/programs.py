@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional
 
 from ..core.scheduler import SchedulerConfig
 from ..graph.csr import CSRGraph
+from ..obs import span
 from .program import AtosProgram
 
 
@@ -38,14 +39,17 @@ def build_program(algorithm: str, graph: CSRGraph, cfg: SchedulerConfig,
     keyword arguments (BFS ``source``/``strategy``, PageRank ``damping``/
     ``eps``/``check_size``, ...); unknown keys raise ``ValueError`` at build
     time, not mid-drain.  All static budgets derive from the *global* graph
-    so a sharded run traces the identical body on every device.
+    so a sharded run traces the identical body on every device.  Runs
+    under the ``atos:build_program`` profiler span.
     """
     factories = _factories()
     if algorithm not in factories:
         raise ValueError(f"unknown algorithm {algorithm!r}; "
                          f"expected one of {algorithms()}")
-    return factories[algorithm](graph, cfg, queue_capacity=queue_capacity,
-                                **dict(params or {}))
+    with span("build_program"):
+        return factories[algorithm](graph, cfg,
+                                    queue_capacity=queue_capacity,
+                                    **dict(params or {}))
 
 
 def reject_unknown_params(algorithm: str, params: Dict[str, Any]) -> None:

@@ -61,6 +61,7 @@ from ..core.scheduler import QueueOps, SchedulerConfig, wavefront_step
 from ..graph.csr import CSRGraph
 from ..launch.mesh import make_shard_mesh, make_shard_mesh2d
 from ..obs import Trace, stacked_rings, unstack_ring
+from ..obs.spans import EXCHANGE
 from ..runtime.program import AtosProgram, ProgramContext, build_merge
 from .exchange import (LANE_LOCAL, NUM_LANES, delivered_width, pop_wavefront,
                        route_tasks)
@@ -322,11 +323,12 @@ def _make_round(program: AtosProgram, cfg: SchedulerConfig, n: int,
             return items, valid, mq2
 
         def push(mq, out, mask):
-            mq2, delivered, meters = route_tasks(
-                mq, out, mask, axis_name=axes, num_shards=s, num_vertices=n,
-                task_vertex=program.task_vertex, route_width=route_width,
-                backend=cfg.backend, mesh_dims=mesh_dims,
-                compress=cfg.compress)
+            with jax.named_scope(EXCHANGE):
+                mq2, delivered, meters = route_tasks(
+                    mq, out, mask, axis_name=axes, num_shards=s,
+                    num_vertices=n, task_vertex=program.task_vertex,
+                    route_width=route_width, backend=cfg.backend,
+                    mesh_dims=mesh_dims, compress=cfg.compress)
             aux.update(meters)
             if defer:
                 aux["delivered"] = delivered   # staged for next round
@@ -500,17 +502,16 @@ def persistent_run_sharded(program, parts: ShardedCSR, mq0, state0,
 
 def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
                          cfg: SchedulerConfig, mesh, route_width=None,
-                         trace: Optional[list] = None, ring0=None,
-                         axes=AXIS, mesh_dims=None, pend_width=None):
+                         ring0=None, axes=AXIS, mesh_dims=None,
+                         pend_width=None):
     """Host loop around one jitted sharded round (discrete kernels).
 
-    ``trace`` collects per-round host-side dicts: global queue sizes,
-    exchange volume (total and per axis), wire ints, donations — the
-    benchmark's per-round telemetry.  ``ring0`` is the stacked per-device
-    :class:`~repro.obs.TraceRing` as in :func:`persistent_run_sharded`: it
-    rides the jitted round as a device operand, so in-loop tracing still
-    costs zero extra host syncs.  In overlap mode the staging buffer rides
-    the same way and is flushed after the loop.
+    ``ring0`` is the stacked per-device :class:`~repro.obs.TraceRing` as in
+    :func:`persistent_run_sharded`: it rides the jitted round as a device
+    operand, so per-round telemetry (queue sizes, exchange volume in total
+    and per axis, wire ints, donations) costs zero extra host syncs.  In
+    overlap mode the staging buffer rides the same way and is flushed after
+    the loop.
     """
     n = parts.num_vertices
     traced = ring0 is not None
@@ -530,8 +531,7 @@ def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
         mq, state, c, pending, ring = round_step(f, mq, state, c,
                                                  pending, ring)
         more = keep_going(mq, state, c, pending)
-        size = mq.size
-        out = (_stacked_view(mq), state, _counters_out(c), more, size[None])
+        out = (_stacked_view(mq), state, _counters_out(c), more)
         if defer:
             out = out + (pending[None],)
         if traced:
@@ -541,7 +541,7 @@ def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
     specs_q = jax.tree.map(lambda _: P(axes), mq0)
     specs_c = jax.tree.map(lambda _: P(axes), ShardCounters.zero())
     in_specs = (P(axes), P(axes), specs_q, P(), specs_c)
-    out_specs = (specs_q, P(), specs_c, P(), P(axes))
+    out_specs = (specs_q, P(), specs_c, P())
     if defer:
         in_specs = in_specs + (P(axes),)
         out_specs = out_specs + (P(axes),)
@@ -559,7 +559,6 @@ def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
     c_st = jax.tree.map(
         lambda x: jnp.zeros((cfg.num_shards,), x.dtype), ShardCounters.zero())
     rounds = 0
-    prev = {"sent": 0, "donated": 0, "wire": 0, "sent_row": 0, "sent_col": 0}
     # pre-round emptiness check mirrors discrete_run's host-synced predicate
     while rounds < cfg.max_rounds:
         if program.empty_means_done:
@@ -576,28 +575,13 @@ def discrete_run_sharded(program, parts: ShardedCSR, mq0, state0,
         if traced:
             operands.append(ring_st)
         outs = step(*operands)
-        mq_st, state, c_st, more, sizes_dev = outs[:5]
-        rest = outs[5:]
+        mq_st, state, c_st, more = outs[:4]
+        rest = outs[4:]
         if defer:
             pending_st = rest[0]
         if traced:
             ring_st = rest[-1]
         rounds += 1
-        if trace is not None:
-            totals = {k: int(np.asarray(getattr(c_st, f)).sum())
-                      for k, f in (("sent", "sent"), ("donated", "donated"),
-                                   ("wire", "wire"), ("sent_row", "sent_row"),
-                                   ("sent_col", "sent_col"))}
-            trace.append({
-                "round": rounds,
-                "sizes": np.asarray(sizes_dev).tolist(),
-                "exchanged": totals["sent"] - prev["sent"],
-                "donated": totals["donated"] - prev["donated"],
-                "wire": totals["wire"] - prev["wire"],
-                "exchanged_row": totals["sent_row"] - prev["sent_row"],
-                "exchanged_col": totals["sent_col"] - prev["sent_col"],
-            })
-            prev = totals
         if not bool(more):
             break
     if defer:
@@ -638,7 +622,7 @@ def run_sharded(
     queue_capacity: Optional[int] = None,
     route_width: Optional[int] = None,
     mesh=None,
-    trace=None,
+    trace: Optional[Trace] = None,
     trace_engine: Optional[str] = None,
     trace_round_offset: int = 0,
     initial_queues: Optional[MultiQueue] = None,
@@ -655,12 +639,11 @@ def run_sharded(
     dimension-ordered two-hop exchange); ``cfg.defer_rounds`` the overlap
     pipeline; ``cfg.compress`` the wire codec — see DESIGN.md §16.
 
-    ``trace`` accepts an :class:`~repro.obs.Trace` (one stacked per-device
-    ring rides the drain; every device appends one row per round in-trace,
-    drained per shard at run end under ``trace_engine`` with absolute round
-    numbers shifted by ``trace_round_offset``) or a legacy ``list``
-    (discrete driver only: per-round host telemetry dicts, at the cost of
-    host syncs).
+    ``trace`` takes an :class:`~repro.obs.Trace`: one stacked per-device
+    ring rides the drain; every device appends one row per round in-trace
+    (queue size, pops, exchanged per axis, wire ints, donations), drained
+    per shard at run end under ``trace_engine`` with absolute round numbers
+    shifted by ``trace_round_offset``.
 
     ``initial_state`` / ``initial_queues`` resume a drain from an explicit
     carry instead of ``program.init()`` (the streaming driver's dirty-seed
@@ -698,9 +681,7 @@ def run_sharded(
                                       axes)
         pend_width = delivered_width(route_w, s, mesh_dims)
 
-    obs = trace if isinstance(trace, Trace) else None
-    legacy = trace if isinstance(trace, list) else None
-    ring0 = stacked_rings(obs.ring(), s) if obs is not None else None
+    ring0 = stacked_rings(trace.ring(), s) if trace is not None else None
     ring_st = None
 
     if cfg.persistent:
@@ -711,9 +692,9 @@ def run_sharded(
     else:
         out = discrete_run_sharded(
             program, parts, mq0, state0, cfg, mesh, route_width=route_w,
-            trace=legacy, ring0=ring0, axes=axes, mesh_dims=mesh_dims,
+            ring0=ring0, axes=axes, mesh_dims=mesh_dims,
             pend_width=pend_width)
-    if obs is not None:
+    if trace is not None:
         mq_st, state, c_st, ring_st = out
     else:
         mq_st, state, c_st = out
@@ -741,13 +722,13 @@ def run_sharded(
         deferred_delivered=int(c.deferred.sum()),
         overlap_rounds=int(c.overlap_rounds.max()),
     )
-    if obs is not None:
+    if trace is not None:
         engine = trace_engine or (
             "sharded.persistent" if cfg.persistent else "sharded.discrete")
         for d in range(s):
-            obs.drain(unstack_ring(ring_st, d), engine=engine,
-                      round_offset=trace_round_offset)
-        obs.add_metric(stats.as_dict())
+            trace.drain(unstack_ring(ring_st, d), engine=engine,
+                        round_offset=trace_round_offset)
+        trace.add_metric(stats.as_dict())
     if final_queues is not None:
         final_queues.append(mq_st)
     return state, stats

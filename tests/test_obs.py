@@ -379,17 +379,23 @@ def test_run_doc_in_registry(g_rmat):
     assert len(runs) == 1
     assert runs[0]["policy"] == "single.persistent"
     assert runs[0]["rounds"] == len(trace.records)
-    assert any(s["name"].startswith("execute") for s in trace.spans)
+    assert [s["name"] for s in trace.spans] == ["init", "drain", "readback"]
 
 
 def test_legacy_list_trace_still_works(g_rmat):
-    """The discrete driver's pre-obs trace hook (a plain list collecting
-    (size, items) tuples) is still honored."""
+    """What the retired ``trace=list`` hook gave the discrete driver —
+    per-round queue size and items — comes from the obs ring's rows, and a
+    plain list is refused by name."""
     cfg = _cfg_for("single.discrete")
     program = build_program("bfs", g_rmat, cfg, params={"source": 0})
-    legacy = []
-    _, _, info = execute(program, g_rmat, cfg, trace=legacy)
-    assert len(legacy) == info["rounds"]
+    trace = Trace()
+    _, stats, info = execute(program, g_rmat, cfg, trace=trace)
+    assert len(trace.records) == info["rounds"]
+    assert sum(r["pops"] for r in trace.records) == int(
+        stats.items_processed)
+    assert trace.records[0]["queue_size"] == 1   # the seed
+    with pytest.raises(TypeError, match="repro.obs.Trace"):
+        execute(program, g_rmat, cfg, trace=[])
 
 
 # ------------------------------------------------- counters & occupancy

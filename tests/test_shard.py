@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import SchedulerConfig
 from repro.graph.generators import grid2d, rmat
+from repro.obs import Trace
 from repro.runtime import build_program
 from repro.shard import (block_bounds, block_size, owner_of,
                          partition_graph, plan_donations, run_sharded,
@@ -171,11 +172,12 @@ def test_one_shard_discrete_driver_traces():
     ref, _ = bfs_bsp(g, 0)
     cfg = SchedulerConfig(num_workers=16, fetch_size=1, persistent=False)
     program = build_program("bfs", g, cfg, params={"source": 0})
-    trace = []
+    trace = Trace()
     state, stats = run_sharded(program, g, cfg, trace=trace)
     np.testing.assert_array_equal(np.asarray(state.dist), np.asarray(ref))
-    assert len(trace) == stats.rounds
-    assert all(t["exchanged"] == 0 for t in trace)
+    assert len(trace.records) == stats.rounds
+    assert sum(r["pops"] for r in trace.records) == stats.items_processed
+    assert all(r["exchanged"] == 0 for r in trace.records)
 
 
 # --------------------------------------------------- 8-device subprocesses
@@ -310,13 +312,22 @@ def test_multidevice_steal_and_global_stop():
         cfg_d = SchedulerConfig(num_workers=8, num_shards=8,
                                 persistent=False, steal_threshold=0.5,
                                 steal_chunk=16)
-        trace = []
+        from repro.obs import Trace
+        trace = Trace()
         dd, di = bfs_speculative(g, 0, cfg_d, trace=trace)
         out['discrete_ok'] = bool((np.asarray(dd) == np.asarray(ref)).all())
         out['discrete_rounds'] = di['rounds']
-        out['trace_len'] = len(trace)
-        out['trace_has_exchange'] = bool(
-            sum(t['exchanged'] for t in trace) > 0)
+        # one ring row per device per round
+        out['trace_len'] = len({r['round'] for r in trace.records})
+        out['trace_rows'] = len(trace.records)
+        out['trace_exchanged'] = sum(r['exchanged'] for r in trace.records)
+        out['info_exchanged'] = di['exchanged']
+        # a mesh of fewer devices than the host has: the rings unstack
+        trace2 = Trace()
+        _, i2 = bfs_speculative(g, 0, SchedulerConfig(
+            num_workers=8, num_shards=2, persistent=False), trace=trace2)
+        out['two_shard_rows'] = len(trace2.records)
+        out['two_shard_rounds'] = i2['rounds']
         print(json.dumps(out))
     """)
     assert res["stop_ok"] and res["all_blocks_reached"], res
@@ -326,7 +337,9 @@ def test_multidevice_steal_and_global_stop():
     assert res["steal_mis"] == 0, res
     assert res["discrete_ok"], res
     assert res["trace_len"] == res["discrete_rounds"]
-    assert res["trace_has_exchange"]
+    assert res["trace_rows"] == 8 * res["discrete_rounds"]
+    assert res["trace_exchanged"] == res["info_exchanged"] > 0
+    assert res["two_shard_rows"] == 2 * res["two_shard_rounds"]
 
 
 def test_multidevice_server_mixes_sharded_and_fused_jobs():

@@ -67,3 +67,37 @@ def test_compaction_kernel_compiles_for_v5e(one_chip, entry):
     ).lower(_spec((width,), jnp.int32, one_chip),
             _spec((width,), jnp.bool_, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_drain_kernels_carry_their_phase_for_v5e(one_chip, monkeypatch):
+    """The single.persistent BFS drain compiled for a v5e: the expansion
+    kernel runs under ``atos.expand``, the compaction kernel under
+    ``atos.push`` (their ``op_name``), so a device trace names both."""
+    import re
+
+    from repro.core import backend, SchedulerConfig
+    from repro.core.scheduler import persistent_drive
+    from repro.graph.generators import rmat
+    from repro.obs.spans import EXPAND, PUSH
+    from repro.runtime import build_program, config_for, parse_policy
+    from repro.runtime.api import _shared_setup
+    from repro.runtime.policy import policy_of
+
+    monkeypatch.setattr(backend, "has_tpu", lambda: True)  # Mosaic kernels
+    graph = rmat(8, edge_factor=8, seed=1)
+    cfg = config_for(SchedulerConfig(num_workers=1024, backend="pallas"),
+                     parse_policy("single.persistent"))
+    program = build_program("bfs", graph, cfg, {"source": 0})
+    queue, state, _, step, cond, _ = _shared_setup(
+        program, graph, cfg, policy_of(cfg), None)
+    carry0 = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                          (queue, state, jnp.int32(0), jnp.int32(0)))
+    text = jax.jit(lambda c: persistent_drive(step, cond, c)).lower(
+        carry0).compile().as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*"
+                         r"op_name=\"([^\"]*)\"", text)
+    assert kernels
+    assert all(f"/{EXPAND}/" in name or f"/{PUSH}/" in name
+               for name in kernels), kernels
+    assert any(f"/{EXPAND}/" in name for name in kernels)
+    assert any(f"/{PUSH}/" in name for name in kernels)
