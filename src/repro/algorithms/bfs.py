@@ -183,6 +183,8 @@ def make_wavefront_fn(graph: CSRGraph, strategy: str, work_budget: int,
             improved &= first_lane[ex.nbr] == lanes
             counter = state.counter.add(jnp.sum(jnp.where(
                 valid & ~truncated, widths, 0)))
+            if strategy == "merge_path":
+                counter = counter.add_units(ex.total, work_budget)
         with jax.named_scope(PUSH):
             # improved (deduplicated) neighbors re-coalesce into chunks;
             # truncated chunks are re-queued whole, unchanged.
@@ -251,6 +253,7 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
         task_width=codec.width,
         work=lambda s: s.counter.work,
         splits=lambda s: s.counter.splits,
+        units=lambda s: s.counter.units,
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(4 * n, 1024),
         dirty_seeds=dirty_seeds,

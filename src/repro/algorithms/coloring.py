@@ -286,7 +286,7 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
                 jnp.where(clash, lane, num_lanes)].set(True, mode="drop")
             bad &= flat_detect
             counter = state.counter.add(jnp.sum(flat_assign.astype(
-                jnp.int32)))
+                jnp.int32))).add_units(ex.total, work_budget)
 
         with jax.named_scope(PUSH):
             # conflicted vertices re-coalesce into assign chunks (identity
@@ -372,6 +372,7 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
         task_width=lambda t: codec.width(natural_code(t)),
         work=lambda s: s.counter.work,
         splits=lambda s: s.counter.splits,
+        units=lambda s: s.counter.units,
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(4 * n, 1024),
         dirty_seeds=conflict_seeds if dirty == "conflicts" else None,

@@ -135,8 +135,8 @@ def _push_wavefront(graph: CSRGraph, damping: float, work_budget: int,
                                 0.0)
             residue = residue.at[jnp.where(ex.valid, ex.nbr, 0)].add(
                 contrib, mode="drop")
-            counter = state.counter.add(jnp.sum(jnp.where(process, widths,
-                                                          0)))
+            counter = state.counter.add(jnp.sum(jnp.where(
+                process, widths, 0))).add_units(ex.total, work_budget)
         return residue, rank, in_queue, counter, truncated
 
     return push
@@ -429,6 +429,7 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
         task_width=codec.width,
         work=lambda s: s.counter.work,
         splits=lambda s: s.counter.splits,
+        units=lambda s: s.counter.units,
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(8 * n, 1024),
         dirty_seeds=dirty_seeds,

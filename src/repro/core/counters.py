@@ -35,11 +35,17 @@ class WorkCounter:
     #: ``"work_counter"``).
     rounds: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))
+    #: work units the merge-path expansions produced, ``min(total,
+    #: budget)`` a round: the lanes of the budget the load-balancing
+    #: search gives an edge (the rest are past the round's total).  0
+    #: where a body expands per item.
+    units: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
 
     @staticmethod
     def zero() -> "WorkCounter":
         return WorkCounter(work=jnp.int32(0), splits=jnp.int32(0),
-                           rounds=jnp.int32(0))
+                           rounds=jnp.int32(0), units=jnp.int32(0))
 
     def add(self, n) -> "WorkCounter":
         return dataclasses.replace(
@@ -48,6 +54,12 @@ class WorkCounter:
     def add_splits(self, n) -> "WorkCounter":
         return dataclasses.replace(
             self, splits=self.splits + jnp.asarray(n, jnp.int32))
+
+    def add_units(self, total, budget: int) -> "WorkCounter":
+        """Count one merge-path expansion of ``total`` units on a
+        ``budget``-lane work list."""
+        return dataclasses.replace(
+            self, units=self.units + jnp.minimum(total, budget))
 
     def bump_round(self) -> "WorkCounter":
         return dataclasses.replace(self, rounds=self.rounds + jnp.int32(1))

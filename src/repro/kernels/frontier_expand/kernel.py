@@ -8,17 +8,40 @@ rank(k) = k - scan[owner-1].
 
 GPU implementations binary-search the scan per thread (branchy, divergent).
 TPU adaptation: the VPU has no efficient per-lane gather but eats 8x128
-broadcast compares — so we replace the binary search with a dense
-compare-count:
+broadcast compares — so we replace the binary search with a compare-count:
 
     owner(k) = sum_j [scan[j] <= k]          (count of rows fully before k)
     excl(k)  = max_j  scan[j] * [scan[j] <= k]  (monotone scan -> running max)
 
-Both are [TILE, W] broadcast ops + a reduction: O(TILE*W) VPU work with zero
-gathers/branches, vs O(TILE*log W) gathers for the binary search.  For
-wavefronts W <= 4096 the compare-count is faster on the VPU than serialized
-gathers by napkin math (a [1024, 2048] i32 compare+reduce is ~2 Mop against
-~11 serial gather rounds with 8-deep dependency chains).
+**Windowed search.**  Each grid step holds TILE units [lo, lo + TILE).
+The scan is monotone, so the rows wholly before the tile (scan <= lo) add
+one each to ``owner`` and their last value to the running max, and the rows
+whose scan exceeds the tile's last live unit add nothing: only the rows
+in between can change an answer.  Two [1, W] compare-counts find them
+(j0 = #(scan <= lo), j1 = #(scan <= min(lo + TILE - 1, total - 1))), and
+the step compares its units against the 128-row scan chunks that hold rows
+[j0, j1] only.  Rows j0 and j1 own the tile's first and last live units,
+and each row of nonzero degree between them owns one or more units in
+between, so the window spans at most TILE such rows: one or
+two chunks at the mean degrees the benchmark's graphs pop (13 to 48), out
+of W / 128 = 32 for a 4096-task wavefront.  The worst case for one step
+is a window that spans every row (a few units spread over zero-degree
+rows through the whole wavefront): that step sweeps all W / 128 chunks,
+the [TILE, W] compares of an unwindowed search, plus the loop.  A call
+cannot pay that on every step: the windows of consecutive tiles follow
+one another and share one chunk at most, so a call sweeps at most
+(live tiles + W / 128 - 1) chunks in all.
+
+Tiles at or past the round's total (``pop_upto`` fills a prefix of the
+wavefront, so the budget's tail carries no edge) skip the search and write
+what it would give there: every row lies before such a unit, so
+owner = W and rank = k - scan[W - 1].  Every output lane is the
+unwindowed search's, bit for bit.
+
+Layout: units run along lanes and a chunk's 128 rows along sublanes (the
+chunk is broadcast and transposed once per chunk), so both reductions are
+elementwise vreg adds and maxes over sublanes, and the results are
+lane-dense [1, TILE] rows with no relayout.
 
 Block layout: the scan (padded to a lane multiple) is VMEM-resident and
 shared by every grid step; each grid step produces one TILE of (owner, rank).
@@ -30,11 +53,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ...core.backend import resolve_interpret
 
 TILE = 1024  # work units per grid step (8 sublanes x 128 lanes)
+CHUNK = 128  # scan rows per compare block (one lane width)
 
 
 def _lbs_kernel(scan_ref, owner_ref, rank_ref, *, w: int):
@@ -44,15 +67,51 @@ def _lbs_kernel(scan_ref, owner_ref, rank_ref, *, w: int):
     owner_ref: [1, TILE] int32 owner row per work unit
     rank_ref:  [1, TILE] int32 rank within the owner row
     """
-    t = pl.program_id(0)
-    k = t * TILE + jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
-    scan = scan_ref[...]  # [1, W]
-    # [TILE, W] broadcast compare: row i <=> work unit k_i
-    le = (scan <= k.reshape(TILE, 1)).astype(jnp.int32)        # [TILE, W]
-    owner = jnp.sum(le, axis=1, dtype=jnp.int32)               # [TILE]
-    excl = jnp.max(scan * le, axis=1)                          # [TILE]
-    owner_ref[...] = owner.reshape(1, TILE)
-    rank_ref[...] = (k.reshape(TILE) - excl).reshape(1, TILE)
+    lo = pl.program_id(0) * TILE
+    scan = scan_ref[...]                                       # [1, W]
+    total = jnp.max(scan)                                      # scan[W-1]
+    k = lo + jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
+
+    @pl.when(lo >= total)
+    def _past_total():
+        owner_ref[...] = jnp.full((1, TILE), w, jnp.int32)
+        rank_ref[...] = k - total
+
+    @pl.when(lo < total)
+    def _search():
+        hi = jnp.minimum(lo + TILE - 1, total - 1)  # last live unit
+        j0 = jnp.sum((scan <= lo).astype(jnp.int32))
+        j1 = jnp.sum((scan <= hi).astype(jnp.int32))
+        c0 = j0 // CHUNK
+        c1 = j1 // CHUNK  # j1 < w: scan[w-1] = total > hi
+        # rows before chunk c0 all lie before the tile
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+        owner0 = jnp.full((1, TILE), c0 * CHUNK, jnp.int32)
+        excl0 = jnp.full((1, TILE), jnp.max(
+            jnp.where(col < c0 * CHUNK, scan, 0)), jnp.int32)
+
+        def chunk(c, carry):
+            owner, excl = carry
+            part = scan_ref[:, pl.ds(pl.multiple_of(c * CHUNK, CHUNK),
+                                     CHUNK)]                   # [1, 128]
+            # rows[j, :] = part[j]: the chunk's rows down the sublanes
+            rows = jnp.transpose(jnp.broadcast_to(part, (CHUNK, CHUNK)))
+            counts, maxes = [], []
+            for g in range(TILE // CHUNK):
+                le = rows <= k[:, g * CHUNK:(g + 1) * CHUNK]  # [128, 128]
+                counts.append(jnp.sum(le.astype(jnp.int32), axis=0,
+                                      keepdims=True))
+                maxes.append(jnp.max(jnp.where(le, rows, 0), axis=0,
+                                     keepdims=True))
+            return (owner + jnp.concatenate(counts, axis=1),
+                    jnp.maximum(excl, jnp.concatenate(maxes, axis=1)))
+
+        owner, excl = jax.lax.fori_loop(c0, c1 + 1, chunk, (owner0, excl0))
+        # units of this tile at or past the total: every row lies before
+        # them, not only the window's, as in the past-total branch
+        past = k >= total
+        owner_ref[...] = jnp.where(past, w, owner)
+        rank_ref[...] = k - jnp.where(past, total, excl)
 
 
 @functools.partial(jax.jit, static_argnames=("budget", "interpret"))
@@ -64,7 +123,7 @@ def lbs_pallas(scan: jax.Array, budget: int, interpret: bool | None = None):
     """
     interpret = resolve_interpret(interpret)
     w = scan.shape[0]
-    w_pad = max(128, -(-w // 128) * 128)
+    w_pad = max(CHUNK, -(-w // CHUNK) * CHUNK)
     # pad with the last scan value so padded rows own zero work units
     last = scan[-1] if w > 0 else jnp.int32(0)
     scan_p = jnp.full((1, w_pad), last, jnp.int32).at[0, :w].set(scan)

@@ -60,6 +60,7 @@ TRACE_FIELDS: Tuple[str, ...] = (
     "exchanged_col",  # cross-device payload ints, column-axis hop (or 1-D)
     "wire",        # metered wire ints (compressed words when codec is on)
     "deferred",    # staged overlap arrivals delivered this round
+    "units",       # merge-path work units expanded: min(total, budget)
 )
 
 NUM_FIELDS = len(TRACE_FIELDS)

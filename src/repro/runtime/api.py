@@ -144,14 +144,15 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
     The traced carry is ``(*inner, ring)`` — the ring rides **last**, so the
     ``carry[2]``/``carry[3]`` index conventions every driver relies on are
     untouched.  Each round appends one structured record (pre-pop queue
-    size, pops, pushes, per-round work/split deltas) with pure in-trace
+    size, pops, pushes, per-round work/split/unit deltas) with pure in-trace
     ``.at[]`` writes — zero host syncs; the wrapped ``cond`` simply strips
-    the ring.  Work/splits deltas come from ``program.work``/``.splits``
-    when declared (traced scalars), else 0.  The bookkeeping runs under the
-    ``atos.ring`` device scope.
+    the ring.  Work/splits/units deltas come from ``program.work``,
+    ``.splits`` and ``.units`` when declared (traced scalars), else 0.
+    The bookkeeping runs under the ``atos.ring`` device scope.
     """
     work_of = program.work if program is not None else None
     splits_of = program.splits if program is not None else None
+    units_of = program.units if program is not None else None
 
     def traced_step(carry):
         *inner, ring = carry
@@ -164,10 +165,11 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
             work = work_of(s1) - work_of(s0) if work_of is not None else 0
             splits = (splits_of(s1) - splits_of(s0)
                       if splits_of is not None else 0)
+            units = units_of(s1) - units_of(s0) if units_of is not None else 0
             ring = ring.record(
                 round=r0, lane=lane, queue_size=size_before, pops=pops,
                 pushes=ops.size(q1) - size_before + pops, work=work,
-                splits=splits, donated=0, exchanged=0)
+                splits=splits, units=units, donated=0, exchanged=0)
         return q1, s1, r1, p1, ring
 
     def traced_cond(carry):

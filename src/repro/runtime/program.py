@@ -98,11 +98,11 @@ def _or_delta(prev: jax.Array, new: jax.Array, axis_name: str) -> jax.Array:
 def _merge_work_counter(prev, new, axis_name: str):
     """Field-level merge for a whole :class:`~repro.core.counters.WorkCounter`.
 
-    ``work``/``splits`` are single-writer additive per round (delta-psum,
-    exactly ``sum_delta``), but ``rounds`` ticks in lockstep on every replica
-    (``wavefront_step`` bumps it unconditionally), so it must be taken as-is
-    — delta-summing a replicated tick would multiply the round count by the
-    shard count every round.
+    ``work``/``splits``/``units`` are single-writer additive per round
+    (delta-psum, exactly ``sum_delta``), but ``rounds`` ticks in lockstep on
+    every replica (``wavefront_step`` bumps it unconditionally), so it must
+    be taken as-is — delta-summing a replicated tick would multiply the
+    round count by the shard count every round.
     """
     from ..core.counters import WorkCounter  # local: keep layering one-way
 
@@ -110,7 +110,8 @@ def _merge_work_counter(prev, new, axis_name: str):
     return dataclasses.replace(
         new,
         work=delta_psum(prev.work, new.work, axis_name),
-        splits=delta_psum(prev.splits, new.splits, axis_name))
+        splits=delta_psum(prev.splits, new.splits, axis_name),
+        units=delta_psum(prev.units, new.units, axis_name))
 
 
 def _is_work_counter(x) -> bool:
@@ -213,6 +214,9 @@ class AtosProgram:
     #: optional state -> chunks split by the formation threshold (the
     #: granularity dial's schedule-deterministic meter; see WorkCounter)
     splits: Optional[Callable[[Any], jax.Array]] = None
+    #: optional state -> work units the merge-path expansions produced
+    #: (``WorkCounter.units``: how many budget lanes the LBS filled)
+    units: Optional[Callable[[Any], jax.Array]] = None
     ideal_work: int = 0
     #: capacity hint when the caller does not size the queue explicitly
     default_queue_capacity: int = 1024

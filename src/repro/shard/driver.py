@@ -298,6 +298,7 @@ def _make_round(program: AtosProgram, cfg: SchedulerConfig, n: int,
             work0 = program.work(state) if program.work is not None else 0
             splits0 = (program.splits(state)
                        if program.splits is not None else 0)
+            units0 = program.units(state) if program.units is not None else 0
         donated = jnp.int32(0)
         triggered = jnp.bool_(False)
         if steal_on:
@@ -343,15 +344,19 @@ def _make_round(program: AtosProgram, cfg: SchedulerConfig, n: int,
             always_run_body=True)
         if ring is not None:
             # one row per device per round, written in-trace (zero syncs):
-            # work/splits are the device-local pre-merge deltas, so summing
-            # a round's rows across lanes reassembles the global round.
+            # work/splits/units are the device-local pre-merge deltas, so
+            # summing a round's rows across lanes reassembles the global
+            # round.
             work1 = program.work(new_state) if program.work is not None else 0
             splits1 = (program.splits(new_state)
                        if program.splits is not None else 0)
+            units1 = (program.units(new_state)
+                      if program.units is not None else 0)
             ring = ring.record(
                 round=c.rounds, lane=me, queue_size=size_before,
                 pops=n_valid, pushes=mq.size - size_before + n_valid,
                 work=work1 - work0, splits=splits1 - splits0,
+                units=units1 - units0,
                 donated=donated, exchanged=aux["sent"],
                 exchanged_row=aux["sent_row"], exchanged_col=aux["sent_col"],
                 wire=aux["wire"], deferred=deferred_n)

@@ -8,19 +8,60 @@ rng = np.random.default_rng(0)
 
 
 # ----------------------------------------------------------- LBS kernel
-@pytest.mark.parametrize("w,budget", [(1, 128), (7, 64), (32, 1024),
-                                      (100, 2048), (257, 4096), (1000, 1024)])
-def test_lbs_kernel_matches_ref(w, budget):
+def _degrees(kind, w):
+    """Row degrees of a wavefront of ``w`` popped tasks."""
+    if kind == "uniform":
+        return rng.integers(0, 9, size=w)
+    deg = np.zeros(w, np.int64)
+    if kind == "hub":            # hubs spanning many tiles, total > budget
+        deg[:] = rng.integers(0, 30, size=w)
+        deg[[100, 2000]] = [40_000, 100_000]
+    elif kind == "invalid_tail":  # low degree, empty tail: total << budget
+        deg[:1600] = rng.integers(5, 20, size=1600)
+    elif kind == "spread_zeros":  # the window spans every scan chunk
+        deg[::37] = rng.integers(1, 3, size=deg[::37].shape[0])
+    elif kind == "wide":          # odd budget, last tiles partly live
+        deg[:] = rng.integers(0, 60, size=w)
+    return deg                   # "zero": an all-zero scan
+
+
+# the benchmark's shapes: wavefront 4096, budget 2^17 or floored at a
+# graph's largest degree (tests/test_tpu_compile.py)
+LBS_CASES = [pytest.param(w, b, "uniform", id=f"{w}-{b}")
+             for w, b in [(1, 128), (7, 64), (32, 1024), (100, 2048),
+                          (257, 4096), (1000, 1024)]] + [
+    pytest.param(4096, 131072, kind, id=kind)
+    for kind in ("hub", "invalid_tail", "spread_zeros", "zero")] + [
+    pytest.param(4096, 163_558, "wide", id="wide-163558")]
+
+
+@pytest.mark.parametrize("w,budget,kind", LBS_CASES)
+def test_lbs_kernel_matches_ref(w, budget, kind):
     from repro.kernels.frontier_expand.kernel import lbs_pallas
     from repro.kernels.frontier_expand.ref import lbs_ref
 
-    deg = rng.integers(0, 9, size=w).astype(np.int32)
-    scan = jnp.cumsum(jnp.asarray(deg))
+    scan = jnp.cumsum(jnp.asarray(_degrees(kind, w), jnp.int32))
     o1, r1 = lbs_pallas(scan, budget)
     o2, r2 = lbs_ref(scan, budget)
     total = min(int(scan[-1]), budget)
     np.testing.assert_array_equal(np.asarray(o1[:total]), np.asarray(o2[:total]))
     np.testing.assert_array_equal(np.asarray(r1[:total]), np.asarray(r2[:total]))
+
+
+@pytest.mark.parametrize("kind", ["invalid_tail", "spread_zeros", "zero"])
+def test_lbs_kernel_lanes_past_total(kind):
+    """Lanes at or past the total read what the full compare-count gives
+    there: every padded row counted (owner = W rounded up to 128) and
+    rank = k - scan[-1]."""
+    from repro.kernels.frontier_expand.kernel import lbs_pallas
+
+    w, budget = 4000, 131072
+    scan = jnp.cumsum(jnp.asarray(_degrees(kind, w), jnp.int32))
+    owner, rank = lbs_pallas(scan, budget)
+    total = int(scan[-1])
+    k = np.arange(total, budget)
+    np.testing.assert_array_equal(np.asarray(owner[total:]), 4096)
+    np.testing.assert_array_equal(np.asarray(rank[total:]), k - total)
 
 
 def test_lbs_kernel_zero_degrees():

@@ -411,6 +411,77 @@ def test_work_counter_rounds_single_source_of_truth(g_rmat):
             info["rounds"], cell
 
 
+def _count_popped_degrees(program, graph, sums):
+    """``program`` with a body that also hands each round's summed degree
+    of the popped tasks to the host (``sums``), outside the counters."""
+    import dataclasses
+
+    def make_body(local_graph, ctx):
+        f = program.make_body(local_graph, ctx)
+
+        def counted(items, valid, state):
+            safe = jnp.where(valid, items, 0)
+            deg = jnp.where(valid, graph.row_ptr[safe + 1]
+                            - graph.row_ptr[safe], 0)
+            jax.debug.callback(lambda d: sums.append(int(d)), jnp.sum(deg))
+            return f(items, valid, state)
+        return counted
+
+    return dataclasses.replace(program, make_body=make_body)
+
+
+@pytest.mark.parametrize("cell", ["single.persistent", "sharded.persistent"])
+def test_units_field_counts_the_expansions_work_units(g_rmat, cell):
+    """With a budget no wavefront reaches, each round's merge-path
+    expansion produces one unit per edge of the popped tasks: the ring's
+    ``units`` rows sum to the popped tasks' summed degrees."""
+    cfg = _cfg_for(cell)
+    budget = int(g_rmat.row_ptr[-1])
+    program = build_program("bfs", g_rmat, cfg,
+                            params={"source": 0, "work_budget": budget})
+    sums = []
+    trace = Trace()
+    state, _, info = execute(_count_popped_degrees(program, g_rmat, sums),
+                             g_rmat, cfg, trace=trace)
+    jax.effects_barrier()
+    assert len(sums) == info["rounds"]
+    assert sum(r["units"] for r in trace.records) == sum(sums) > 0
+    assert int(state.counter.units) == sum(sums)
+
+
+def test_units_field_costs_one_add_with_the_ring_off(g_rmat, monkeypatch):
+    """Ring off, the compiled drain's while body computes nothing more
+    than with the ``units`` count taken out, bar the one scalar add."""
+    import re
+    from collections import Counter
+
+    from repro.core.scheduler import persistent_drive
+    from repro.runtime.api import _shared_setup
+    from repro.runtime.policy import policy_of
+
+    cfg = _cfg_for("single.persistent")
+    plumbing = {"get-tuple-element", "tuple", "parameter", "constant",
+                "copy", "bitcast"}
+
+    def body_ops():
+        program = build_program("bfs", g_rmat, cfg, params={"source": 0})
+        queue, state, _, step, cond, _ = _shared_setup(
+            program, g_rmat, cfg, policy_of(cfg), None)
+        carry0 = (queue, state, jnp.int32(0), jnp.int32(0))
+        text = jax.jit(lambda c: persistent_drive(step, cond, c)).lower(
+            carry0).compile().as_text()
+        body, = re.findall(r"while\(.*?body=(%?[\w.\-]+)", text)
+        block = re.search("^" + re.escape(body) + r" .*?\{\n(.*?)\n\}",
+                          text, re.S | re.M).group(1)
+        ops = Counter(re.findall(r"= \S+ ([\w\-]+)\(", block))
+        return sum(n for op, n in ops.items() if op not in plumbing)
+
+    counted = body_ops()
+    monkeypatch.setattr(WorkCounter, "add_units",
+                        lambda self, total, budget: self)
+    assert 0 <= counted - body_ops() <= 1
+
+
 def test_occupancy_vertex_denominated_at_g4():
     """The granularity > 1 occupancy fix: the numerator counts vertices
     (chunk-width weighted), the denominator counts the vertex budget

@@ -58,6 +58,28 @@ def test_lbs_kernel_compiles_for_v5e(one_chip, wavefront):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_lbs_kernel_is_read_by_the_benchmark_for_v5e(one_chip):
+    """The benchmark finds the LBS in a trace by its op name and counts
+    its bytes from the one scan operand and the owner result: the
+    compiled call at the cells' widths (wavefront 4096, budget 2^17)
+    still reads as the benchmark expects."""
+    from jax._src.lib import xla_client
+
+    from bench import kernels
+
+    compiled = jax.jit(
+        lambda scan: lbs_pallas(scan, 1 << 17, interpret=False)
+    ).lower(_spec((4096,), jnp.int32, one_chip)).compile()
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True  # as a device trace names the op
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(options)
+    calls = [line.strip() for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert kernels.kernel_of(calls[0]) == "lbs_pallas"
+    assert kernels.lbs_bytes(calls[0]) == 4 * 4096 + 8 * 131072
+
+
 @pytest.mark.parametrize("entry", [compact_tiles_pallas, compact],
                          ids=["tiles", "stitched"])
 def test_compaction_kernel_compiles_for_v5e(one_chip, entry):
