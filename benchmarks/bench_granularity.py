@@ -101,7 +101,7 @@ def _child() -> None:
             cell["bfs_shard"] = {
                 "rounds": stats.rounds,
                 "exchanged_total": stats.exchanged,
-                "splits": program.splits_of(state),
+                "splits": program.count_of("splits", state),
                 "wall_seconds": wall,
             }
             entry["g"][str(gr)] = cell

@@ -240,7 +240,7 @@ for name, g in graphs.items():
         state, stats = run_sharded(program, g, cfg)
         cell['bfs_shard'] = {{'rounds': stats.rounds,
                               'exchanged_total': stats.exchanged,
-                              'splits': program.splits_of(state)}}
+                              'splits': program.count_of('splits', state)}}
         entry[str(gr)] = cell
     out[name] = entry
 print(json.dumps(out))

@@ -22,8 +22,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, chunk_degrees,
-                    adjacency_of, chunk_seeds, coalesce_chunks,
+from ..core import (STATE_COUNTERS, ChunkCodec, SchedulerConfig, WorkCounter,
+                    chunk_degrees, adjacency_of, chunk_seeds, coalesce_chunks,
                     expand_merge_path, expand_per_item, flatten_chunks)
 from ..graph.csr import CSRGraph
 from ..obs import Trace
@@ -251,9 +251,7 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
         merge={"dist": "pmin", "counter": "work_counter"},
         task_vertex=codec.head,
         task_width=codec.width,
-        work=lambda s: s.counter.work,
-        splits=lambda s: s.counter.splits,
-        units=lambda s: s.counter.units,
+        counters=STATE_COUNTERS,
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(4 * n, 1024),
         dirty_seeds=dirty_seeds,

@@ -31,8 +31,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, adjacency_of,
-                    chunk_degrees, chunk_seeds, coalesce_chunks,
+from ..core import (STATE_COUNTERS, ChunkCodec, SchedulerConfig, WorkCounter,
+                    adjacency_of, chunk_degrees, chunk_seeds, coalesce_chunks,
                     expand_merge_path, flatten_chunks, gather_neighbors)
 from ..graph.csr import CSRGraph
 from ..obs import Trace
@@ -47,6 +47,10 @@ from .common import chunking_for, default_work_budget, max_degree_of
 class ColorState:
     colors: jax.Array   # int32 [n], -1 = uncolored
     counter: WorkCounter  # assign tasks processed (Table 4 unit: ratio vs n)
+    #: rounds whose assigns built the full-palette forbidden table (some
+    #: lane had every one of the first ``FIRST_COLORS`` colors taken)
+    wide_picks: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))
 
 
 def _gather_neighbor_colors(graph, vids, valid, max_degree):
@@ -69,7 +73,8 @@ FIRST_COLORS = 1024
 
 
 def _first_free_color(lane, nbr_colors, live, num_lanes, max_colors):
-    """Per lane: smallest color in [0, max_colors) on none of its live edges.
+    """Per lane: smallest color in [0, max_colors) on none of its live
+    edges, and whether the full-palette table was built for it.
 
     The forbidden table is built by one scatter over the edges — O(edges +
     lanes x colors), never the [lanes, edges, colors] one-hot, which at a
@@ -87,10 +92,11 @@ def _first_free_color(lane, nbr_colors, live, num_lanes, max_colors):
                 jnp.all(forbidden, axis=1))
 
     if max_colors <= FIRST_COLORS:
-        return first_free(max_colors)[0]
+        return first_free(max_colors)[0], jnp.bool_(False)
     pick, full = first_free(FIRST_COLORS)
-    return jax.lax.cond(jnp.any(full), lambda: first_free(max_colors)[0],
-                        lambda: pick)
+    wide = jnp.any(full)
+    return jax.lax.cond(wide, lambda: first_free(max_colors)[0],
+                        lambda: pick), wide
 
 
 def _min_free_color(colors, nbr, in_row, max_colors):
@@ -263,9 +269,9 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
         with jax.named_scope(APPLY):
             # ---- phase A: assigns (all reads see pre-wavefront colors =
             # stale speculation, exactly the GPU race the paper analyzes)
-            pick = _first_free_color(lane, state.colors[ex.nbr],
-                                     live & is_assign[ex.owner], num_lanes,
-                                     max_colors)
+            pick, wide = _first_free_color(
+                lane, state.colors[ex.nbr], live & is_assign[ex.owner],
+                num_lanes, max_colors)
             # duplicate assign tasks for one vertex cannot exist (1 assign
             # -> 1 detect -> at most 1 re-assign, and chunk members are
             # distinct), so this scatter has unique targets
@@ -303,7 +309,9 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
             ])
             mask = jnp.concatenate([done_assign, re_mask, truncated])
             counter = counter.add_splits(n_splits)
-        return out, mask, ColorState(colors=colors, counter=counter)
+        return out, mask, ColorState(
+            colors=colors, counter=counter,
+            wide_picks=state.wide_picks + wide.astype(jnp.int32))
 
     return f
 
@@ -367,12 +375,12 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
         init=lambda: init_state(graph, codec, owner_block, threshold),
         make_body=make_body,
         result=lambda s: s.colors,
-        merge={"colors": "sum_delta", "counter": "work_counter"},
+        merge={"colors": "sum_delta", "counter": "work_counter",
+               "wide_picks": "sum_delta"},
         task_vertex=lambda t: codec.head(natural_code(t)),
         task_width=lambda t: codec.width(natural_code(t)),
-        work=lambda s: s.counter.work,
-        splits=lambda s: s.counter.splits,
-        units=lambda s: s.counter.units,
+        counters={**STATE_COUNTERS,
+                  "wide_picks": lambda s: s.wide_picks},
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(4 * n, 1024),
         dirty_seeds=conflict_seeds if dirty == "conflicts" else None,
@@ -401,13 +409,15 @@ def coloring_async(
 
 
 def validate_coloring(graph: CSRGraph, colors) -> bool:
-    """Proper coloring: no edge joins two same-colored vertices; all colored."""
+    """Proper coloring: no edge joins two same-colored vertices; all
+    colored.  Entries of ``col_idx`` past ``row_ptr[n]`` (spare capacity)
+    are not edges."""
     import numpy as np
 
     c = np.asarray(colors)
     if (c < 0).any():
         return False
     rp = np.asarray(graph.row_ptr)
-    ci = np.asarray(graph.col_idx)
+    ci = np.asarray(graph.col_idx)[:rp[-1]]
     src = np.repeat(np.arange(graph.num_vertices), np.diff(rp))
     return bool((c[src] != c[ci]).all())

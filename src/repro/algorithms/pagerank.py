@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import (ChunkCodec, SchedulerConfig, WorkCounter, adjacency_of,
-                    chunk_degrees, chunk_seeds, coalesce_chunks,
+from ..core import (STATE_COUNTERS, ChunkCodec, SchedulerConfig, WorkCounter,
+                    adjacency_of, chunk_degrees, chunk_seeds, coalesce_chunks,
                     expand_merge_path, flatten_chunks)
 from ..graph.csr import CSRGraph
 from ..obs import Trace
@@ -427,9 +427,7 @@ def make_program(graph: CSRGraph, cfg: SchedulerConfig, *,
                "counter": "work_counter"},
         task_vertex=codec.head,
         task_width=codec.width,
-        work=lambda s: s.counter.work,
-        splits=lambda s: s.counter.splits,
-        units=lambda s: s.counter.units,
+        counters=STATE_COUNTERS,
         ideal_work=n,
         default_queue_capacity=queue_capacity or max(8 * n, 1024),
         dirty_seeds=dirty_seeds,

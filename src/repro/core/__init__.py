@@ -7,7 +7,7 @@ from .frontier import (Expansion, adjacency_of, chunk_degrees, chunk_row_of,
                        expand_merge_path, expand_per_item, gather_neighbors)
 from .task import (MAX_GRANULARITY, ChunkCodec, chunk_seeds, coalesce_chunks,
                    flatten_chunks)
-from .counters import WorkCounter, overwork_ratio
+from .counters import STATE_COUNTERS, WorkCounter, overwork_ratio
 
 __all__ = [
     "BACKENDS", "default_interpret", "has_tpu", "resolve_backend",
@@ -18,5 +18,5 @@ __all__ = [
     "expand_merge_path", "expand_per_item", "gather_neighbors",
     "MAX_GRANULARITY", "ChunkCodec", "chunk_seeds", "coalesce_chunks",
     "flatten_chunks",
-    "WorkCounter", "overwork_ratio",
+    "STATE_COUNTERS", "WorkCounter", "overwork_ratio",
 ]

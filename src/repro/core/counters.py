@@ -12,6 +12,7 @@ per algorithm, giving ``overwork = work / ideal`` — the Table 4 metric.
 from __future__ import annotations
 
 import dataclasses
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +64,12 @@ class WorkCounter:
 
     def bump_round(self) -> "WorkCounter":
         return dataclasses.replace(self, rounds=self.rounds + jnp.int32(1))
+
+
+#: ``AtosProgram.counters`` of a state that keeps its ``WorkCounter`` as
+#: ``.counter``: the counter's per-round additive fields, by trace field
+STATE_COUNTERS = {name: operator.attrgetter(f"counter.{name}")
+                  for name in ("work", "splits", "units")}
 
 
 def overwork_ratio(counter: WorkCounter, ideal: int) -> float:

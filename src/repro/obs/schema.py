@@ -61,6 +61,7 @@ TRACE_FIELDS: Tuple[str, ...] = (
     "wire",        # metered wire ints (compressed words when codec is on)
     "deferred",    # staged overlap arrivals delivered this round
     "units",       # merge-path work units expanded: min(total, budget)
+    "wide_picks",  # coloring: 1 where assigns built the full-palette table
 )
 
 NUM_FIELDS = len(TRACE_FIELDS)

@@ -144,32 +144,27 @@ def instrument_step(step, cond, ops: QueueOps, program: Optional[AtosProgram],
     The traced carry is ``(*inner, ring)`` — the ring rides **last**, so the
     ``carry[2]``/``carry[3]`` index conventions every driver relies on are
     untouched.  Each round appends one structured record (pre-pop queue
-    size, pops, pushes, per-round work/split/unit deltas) with pure in-trace
-    ``.at[]`` writes — zero host syncs; the wrapped ``cond`` simply strips
-    the ring.  Work/splits/units deltas come from ``program.work``,
-    ``.splits`` and ``.units`` when declared (traced scalars), else 0.
-    The bookkeeping runs under the ``atos.ring`` device scope.
+    size, pops, pushes, the per-round delta of each counter the program
+    declares: ``AtosProgram.counts``) with pure in-trace ``.at[]`` writes —
+    zero host syncs; the wrapped ``cond`` simply strips the ring.  A
+    counter the program does not declare reads 0.  The bookkeeping runs
+    under the ``atos.ring`` device scope.
     """
-    work_of = program.work if program is not None else None
-    splits_of = program.splits if program is not None else None
-    units_of = program.units if program is not None else None
+    counts = program.counts if program is not None else lambda s: {}
 
     def traced_step(carry):
         *inner, ring = carry
         q0, s0, r0, p0 = inner
         with jax.named_scope(RING):
             size_before = ops.size(q0)
+            c0 = counts(s0)
         q1, s1, r1, p1 = step((q0, s0, r0, p0))
         with jax.named_scope(RING):
             pops = p1 - p0
-            work = work_of(s1) - work_of(s0) if work_of is not None else 0
-            splits = (splits_of(s1) - splits_of(s0)
-                      if splits_of is not None else 0)
-            units = units_of(s1) - units_of(s0) if units_of is not None else 0
+            deltas = {k: v - c0[k] for k, v in counts(s1).items()}
             ring = ring.record(
                 round=r0, lane=lane, queue_size=size_before, pops=pops,
-                pushes=ops.size(q1) - size_before + pops, work=work,
-                splits=splits, units=units, donated=0, exchanged=0)
+                pushes=ops.size(q1) - size_before + pops, **deltas)
         return q1, s1, r1, p1, ring
 
     def traced_cond(carry):
@@ -208,9 +203,8 @@ def _run_shared_core(program: AtosProgram, graph, cfg: SchedulerConfig,
         stats = RunStats(rounds, processed, dropped_of(queue))
         info = {
             "rounds": int(stats.rounds),
-            "work": program.work_of(state),
             "dropped": int(stats.dropped),
-            "splits": program.splits_of(state),
+            **counters_info(program, state),
             # kernel-entry events per drain: persistent/discrete re-enter
             # the expand/push kernels every round (one host dispatch per
             # round for discrete; one while-loop iteration per round for
@@ -222,6 +216,14 @@ def _run_shared_core(program: AtosProgram, graph, cfg: SchedulerConfig,
             obs.drain(carry[4], engine=str(policy))
             obs.add_metric(run_doc(policy, stats, info))
     return ExecutionResult(state, stats, info)
+
+
+def counters_info(program: AtosProgram, state) -> dict:
+    """``info``'s counters: each one the program declares, read to the host
+    at once; ``work`` and ``splits`` read 0 where undeclared."""
+    counts = jax.device_get(program.counts(state))
+    return {"work": 0, "splits": 0,
+            **{name: int(value) for name, value in counts.items()}}
 
 
 def run_doc(policy, stats: RunStats, info: dict) -> dict:
@@ -249,9 +251,8 @@ def _run_sharded(program: AtosProgram, graph, cfg: SchedulerConfig,
                      jnp.int32(sstats.dropped + sstats.route_dropped))
     info = {
         "rounds": sstats.rounds,
-        "work": program.work_of(state),
         "dropped": sstats.dropped + sstats.route_dropped,
-        "splits": program.splits_of(state),
+        **counters_info(program, state),
         "shards": len(sstats.per_device_items),
         "exchanged": sstats.exchanged,
         "donated": sstats.donated,

@@ -17,7 +17,7 @@ replica-merge adapter per engine.  An ``AtosProgram`` packages all of that
     task_vertex(task)         -> head vertex id (ownership/routing/stealing)
     task_width(task)          -> chunk width (vertex-denominated occupancy)
     dirty_seeds(delta, state) -> optional incremental re-seed (repro/stream)
-    result(state), work(state), ideal_work
+    result(state), counters, ideal_work
 
 The body builders receive a :class:`ProgramContext` describing *where* the
 body will run: wavefront width, backend, and — under the sharded topology —
@@ -45,7 +45,8 @@ merged`` for exotic states.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, Mapping, NamedTuple, Optional, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -210,13 +211,15 @@ class AtosProgram:
     #: task -> chunk width in vertices; drives vertex-denominated queue
     #: occupancy, fairness quotas, and steal plans (DESIGN.md section 12).
     task_width: Callable[[jax.Array], jax.Array] = unit_task_width
-    work: Optional[Callable[[Any], jax.Array]] = None
-    #: optional state -> chunks split by the formation threshold (the
-    #: granularity dial's schedule-deterministic meter; see WorkCounter)
-    splits: Optional[Callable[[Any], jax.Array]] = None
-    #: optional state -> work units the merge-path expansions produced
-    #: (``WorkCounter.units``: how many budget lanes the LBS filled)
-    units: Optional[Callable[[Any], jax.Array]] = None
+    #: the state counters the program keeps, by trace field
+    #: (``obs/schema.TRACE_FIELDS``): name -> state -> traced int scalar.
+    #: ``execute`` reports each in ``info``, the trace ring records each
+    #: one's per-round delta, and one not declared reads 0.  ``work`` is the
+    #: tasks processed (Table 4's unit), ``splits`` the chunks split by the
+    #: formation threshold, ``units`` the merge-path work units expanded
+    #: (``WorkCounter``), ``wide_picks`` coloring's full-palette rounds.
+    counters: Mapping[str, Callable[[Any], jax.Array]] = dataclasses.field(
+        default_factory=dict)
     ideal_work: int = 0
     #: capacity hint when the caller does not size the queue explicitly
     default_queue_capacity: int = 1024
@@ -242,15 +245,14 @@ class AtosProgram:
     def merge_fn(self) -> Callable[[Any, Any, str], Any]:
         return build_merge(self.merge)
 
-    def work_of(self, state) -> int:
-        if self.work is None:
-            return 0
-        return int(self.work(state))
+    def count_of(self, name: str, state) -> int:
+        """The counter ``name`` of ``state`` on the host (0 if undeclared)."""
+        read = self.counters.get(name)
+        return 0 if read is None else int(read(state))
 
-    def splits_of(self, state) -> int:
-        if self.splits is None:
-            return 0
-        return int(self.splits(state))
+    def counts(self, state) -> Dict[str, jax.Array]:
+        """Trace field -> traced value of each declared counter."""
+        return {name: read(state) for name, read in self.counters.items()}
 
     # ----------------------------------------------------- legacy adapters
     @property

@@ -385,7 +385,7 @@ class TaskServer:
         tel.admitted_round = tel.completed_round = 0
         tel.rounds_active = sstats.rounds
         tel.items_processed = sstats.items_processed
-        tel.work = int(program.work(state))
+        tel.work = program.count_of("work", state)
         tel.dropped = sstats.dropped + sstats.route_dropped
         job.telemetry = tel
         if self.strict_drops and tel.dropped > 0:

@@ -295,10 +295,7 @@ def _make_round(program: AtosProgram, cfg: SchedulerConfig, n: int,
             mq = mq.push(LANE_LOCAL, pending, pv, backend=cfg.backend)
         if ring is not None:
             size_before = mq.size  # pre-steal, pre-pop replica occupancy
-            work0 = program.work(state) if program.work is not None else 0
-            splits0 = (program.splits(state)
-                       if program.splits is not None else 0)
-            units0 = program.units(state) if program.units is not None else 0
+            counts0 = program.counts(state)
         donated = jnp.int32(0)
         triggered = jnp.bool_(False)
         if steal_on:
@@ -344,20 +341,15 @@ def _make_round(program: AtosProgram, cfg: SchedulerConfig, n: int,
             always_run_body=True)
         if ring is not None:
             # one row per device per round, written in-trace (zero syncs):
-            # work/splits/units are the device-local pre-merge deltas, so
+            # the counters' deltas are the device-local pre-merge ones, so
             # summing a round's rows across lanes reassembles the global
             # round.
-            work1 = program.work(new_state) if program.work is not None else 0
-            splits1 = (program.splits(new_state)
-                       if program.splits is not None else 0)
-            units1 = (program.units(new_state)
-                      if program.units is not None else 0)
+            deltas = {k: v - counts0[k]
+                      for k, v in program.counts(new_state).items()}
             ring = ring.record(
                 round=c.rounds, lane=me, queue_size=size_before,
                 pops=n_valid, pushes=mq.size - size_before + n_valid,
-                work=work1 - work0, splits=splits1 - splits0,
-                units=units1 - units0,
-                donated=donated, exchanged=aux["sent"],
+                **deltas, donated=donated, exchanged=aux["sent"],
                 exchanged_row=aux["sent_row"], exchanged_col=aux["sent_col"],
                 wire=aux["wire"], deferred=deferred_n)
         # round-synchronous replica reconciliation: after this every device

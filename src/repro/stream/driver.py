@@ -338,8 +338,8 @@ def run_stream(
                 eff = applied.num_effective
             seeds = jnp.asarray(seeds, jnp.int32)
             seeds_count = int(seeds.shape[0])
-            pre_work = program.work_of(state)
-            pre_splits = program.splits_of(state)
+            pre_work = program.count_of("work", state)
+            pre_splits = program.count_of("splits", state)
 
         def save_snapshot(queue_tree, st, r, p):
             nonlocal tick
@@ -402,8 +402,8 @@ def run_stream(
         records.append(BatchRecord(
             batch=b, incremental=was_incremental, seeds=seeds_count,
             effective_ops=eff, rounds=rounds, processed=processed,
-            work=program.work_of(state) - pre_work,
-            splits=program.splits_of(state) - pre_splits,
+            work=program.count_of("work", state) - pre_work,
+            splits=program.count_of("splits", state) - pre_splits,
             dropped=dropped,
             # a restoring batch's commit happened inside replay_commits —
             # the slotted counters still hold exactly that batch's numbers

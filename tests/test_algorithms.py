@@ -148,6 +148,48 @@ def test_coloring_truncated_hub_wavefront_stays_valid():
     assert out["jnp"][1] == out["pallas"][1]
 
 
+def test_coloring_wide_picks_counts_full_palette_rounds(monkeypatch):
+    """With the first forbidden table cut to 4 colors, the hubs, whose
+    neighbours hold more, pick from the full-palette table: the rounds
+    that built it are counted, the colors are those of the uncut table
+    (still proper), and the ring's per-round field sums to ``info``."""
+    import repro.algorithms.coloring as C
+    from repro.obs import Trace
+    from repro.runtime import build_program, execute
+
+    g = GRAPHS["scale_free"]
+    cfg = SchedulerConfig(num_workers=8, max_rounds=100000)
+    uncut = execute(build_program("coloring", g, cfg), g, cfg)
+    assert uncut.info["wide_picks"] == 0
+    monkeypatch.setattr(C, "FIRST_COLORS", 4)
+    trace = Trace()
+    res = execute(build_program("coloring", g, cfg), g, cfg, trace=trace)
+    assert int(jnp.max(res.state.colors)) >= 4
+    assert 0 < res.info["wide_picks"] < res.info["rounds"]
+    assert sum(r["wide_picks"] for r in trace.records) == \
+        res.info["wide_picks"] == int(res.state.wide_picks)
+    assert validate_coloring(g, res.state.colors)
+    np.testing.assert_array_equal(np.asarray(res.state.colors),
+                                  np.asarray(uncut.state.colors))
+
+
+def test_validate_coloring_ignores_spare_csr_capacity():
+    """``col_idx`` entries past ``row_ptr[n]`` (a fixed capacity, as the
+    benchmark's graphs hold) are not edges."""
+    from repro.graph.csr import CSRGraph
+
+    g = GRAPHS["scale_free"]
+    colors, _ = coloring_bsp(g)
+    m = int(g.row_ptr[-1])
+    padded = CSRGraph(row_ptr=g.row_ptr, col_idx=jnp.concatenate(
+        [g.col_idx, jnp.zeros((m // 2 + 3,), jnp.int32)]))
+    assert validate_coloring(padded, colors)
+    rp = np.asarray(g.row_ptr)
+    u = int(np.argmax(np.diff(rp) > 0))  # an edge u-v; make it conflict
+    v = int(g.col_idx[rp[u]])
+    assert not validate_coloring(padded, colors.at[v].set(colors[u]))
+
+
 def test_coloring_small_explicit_budget_stays_valid():
     """An explicit coloring work_budget below max_degree is floored at it
     (progress guarantee); the heavily truncated drain still colors
@@ -171,7 +213,7 @@ def test_coloring_small_explicit_budget_stays_valid():
 def test_first_free_color_matches_mex(monkeypatch, first_colors):
     """The narrow forbidden table (and its full-palette fallback when a
     lane has every narrow color taken) gives each lane the smallest color
-    on none of its live edges."""
+    on none of its live edges, and says whether the fallback ran."""
     import repro.algorithms.coloring as C
 
     monkeypatch.setattr(C, "FIRST_COLORS", first_colors)
@@ -180,12 +222,15 @@ def test_first_free_color_matches_mex(monkeypatch, first_colors):
     lane = rng.integers(0, lanes, edges).astype(np.int32)
     nbr_colors = rng.integers(-1, 12, edges).astype(np.int32)
     live = rng.random(edges) < 0.8
-    got = np.asarray(C._first_free_color(
+    got, wide = C._first_free_color(
         jnp.asarray(lane), jnp.asarray(nbr_colors), jnp.asarray(live),
-        lanes, max_colors))
+        lanes, max_colors)
+    got = np.asarray(got)
     for i in range(lanes):
         taken = set(nbr_colors[(lane == i) & live & (nbr_colors >= 0)])
         assert got[i] == min(set(range(max_colors)) - taken), i
+    # some lane has both narrow colors taken at width 2; none reaches 1024
+    assert bool(wide) == (first_colors == 2)
 
 
 def test_coloring_async_less_overwork_than_bsp():
