@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from ..core import (STATE_COUNTERS, ChunkCodec, SchedulerConfig, WorkCounter,
                     chunk_degrees, adjacency_of, chunk_seeds, coalesce_chunks,
-                    expand_merge_path, expand_per_item, flatten_chunks)
+                    expand_merge_path, expand_per_item, fitting_units,
+                    flatten_chunks, owner_broadcast)
 from ..graph.csr import CSRGraph
 from ..obs import Trace
 from ..obs.spans import APPLY, EXPAND, PUSH
@@ -140,30 +141,33 @@ def make_wavefront_fn(graph: CSRGraph, strategy: str, work_budget: int,
                 # whole (progress is guaranteed: budget >= max_degree >= any
                 # formed chunk's degree-sum, so the first popped task always
                 # expands fully).
-                deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
-                excl = jnp.cumsum(deg) - deg
-                truncated = valid & (excl + deg > work_budget)
+                scan = jnp.cumsum(chunk_degrees(heads, widths, valid,
+                                                graph.row_ptr))
+                truncated = valid & (scan > work_budget)
+                # edges owned by truncated chunks are excluded entirely:
+                # the chunk is re-queued whole and will relax+push on
+                # re-expansion (if we relaxed the prefix now but suppressed
+                # its pushes, the re-expansion would see "no improvement"
+                # and the neighbor would never be enqueued).  Truncation
+                # is a suffix of the wavefront, so the units of the chunks
+                # that fit are a prefix of the lanes.
+                units = jnp.arange(work_budget, dtype=jnp.int32)
+                live = ex.valid & (units < fitting_units(scan, work_budget))
             else:                         # warp worker: task-parallel only
                 flat_v, flat_valid, _ = flatten_chunks(heads, widths, valid,
                                                        g)
                 ex = expand_per_item(flat_v, flat_valid, rp, cols,
                                      max_degree, overlay=overlay)
                 truncated = jnp.zeros_like(valid)
-            # edges owned by truncated chunks are excluded entirely: the
-            # chunk is re-queued whole and will relax+push on re-expansion
-            # (if we relaxed the prefix now but suppressed its pushes, the
-            # re-expansion would see "no improvement" and the neighbor would
-            # never be enqueued).  (per_item never truncates; its ex.owner
-            # indexes the flattened per-vertex lanes, so the mask below is
-            # the chunk one only on the merge_path branch.)
-            live = (ex.valid & ~truncated[ex.owner]
-                    if strategy == "merge_path" else ex.valid)
+                live = ex.valid
         with jax.named_scope(APPLY):
             # one-row chunks: a unit's source is its popped head, so its
             # distance comes from the wavefront-sized table
-            src_dist = (state.dist[heads][ex.owner]
-                        if strategy == "merge_path" and g == 1
-                        else state.dist[ex.src])
+            if strategy == "merge_path" and g == 1:
+                src_dist, = owner_broadcast(ex.excl, work_budget,
+                                            state.dist[heads])
+            else:
+                src_dist = state.dist[ex.src]
             cand = jnp.where(live, src_dist + 1, INF)
             before = state.dist[ex.nbr]
             tgt = jnp.where(live, ex.nbr, 0)

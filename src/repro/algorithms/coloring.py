@@ -33,7 +33,8 @@ import jax.numpy as jnp
 
 from ..core import (STATE_COUNTERS, ChunkCodec, SchedulerConfig, WorkCounter,
                     adjacency_of, chunk_degrees, chunk_seeds, coalesce_chunks,
-                    expand_merge_path, flatten_chunks, gather_neighbors)
+                    expand_merge_path, fitting_units, flatten_chunks,
+                    gather_neighbors, owner_broadcast)
 from ..graph.csr import CSRGraph
 from ..obs import Trace
 from ..obs.spans import APPLY, EXPAND, PUSH
@@ -248,29 +249,39 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
             codes = jnp.where(is_assign, items - 1, -items - 1)
             codes = jnp.where(valid, codes, 0)
             heads, widths = codec.decode(codes)
-            deg = chunk_degrees(heads, widths, valid, graph.row_ptr)
-            excl = jnp.cumsum(deg) - deg
-            truncated = valid & (excl + deg > work_budget)
+            scan = jnp.cumsum(chunk_degrees(heads, widths, valid,
+                                            graph.row_ptr))
+            truncated = valid & (scan > work_budget)
             ex = expand_merge_path(heads, valid, rp, cols, work_budget,
                                    backend=backend, widths=widths,
                                    max_width=g, overlay=overlay)
-            live = ex.valid & ~truncated[ex.owner]
+            # truncation is a suffix of the wavefront: the units of the
+            # chunks that fit are a prefix of the lanes
+            units = jnp.arange(work_budget, dtype=jnp.int32)
+            live = ex.valid & (units < fitting_units(scan, work_budget))
             # explode chunk tasks into their member vertices: lane kind
             # (assign vs detect) is a chunk property, vertices are per
             # member; each edge's lane is its source row's slot in that
-            # layout
+            # layout (a one-row chunk's lane is its task's).  A unit's kind
+            # is its task's sign: live units belong to valid tasks.
             vids, flat_valid, owner = flatten_chunks(heads, widths, valid, g)
             flat_run = flat_valid & ~truncated[owner]
             flat_assign = flat_run & is_assign[owner]
             flat_detect = flat_run & is_detect[owner]
-            lane = ex.owner * g + (ex.src - heads[ex.owner])
+            if g == 1:
+                kind, = owner_broadcast(ex.excl, work_budget, items)
+                lane = ex.owner
+            else:
+                kind, head = owner_broadcast(ex.excl, work_budget, items,
+                                             heads)
+                lane = ex.owner * g + (ex.src - head)
             num_lanes = vids.shape[0]
 
         with jax.named_scope(APPLY):
             # ---- phase A: assigns (all reads see pre-wavefront colors =
             # stale speculation, exactly the GPU race the paper analyzes)
             pick, wide = _first_free_color(
-                lane, state.colors[ex.nbr], live & is_assign[ex.owner],
+                lane, state.colors[ex.nbr], live & (kind > 0),
                 num_lanes, max_colors)
             # duplicate assign tasks for one vertex cannot exist (1 assign
             # -> 1 detect -> at most 1 re-assign, and chunk members are
@@ -284,9 +295,12 @@ def make_wavefront_fn(graph: CSRGraph, work_budget: int,
             # detection is identical no matter which device processed the
             # wavefront (shard parity).
             detect_colors = colors if fused else state.colors
-            my = (detect_colors[heads][ex.owner] if g == 1
-                  else detect_colors[ex.src])
-            clash = (live & is_detect[ex.owner] & (my >= 0)
+            if g == 1:
+                my, = owner_broadcast(ex.excl, work_budget,
+                                      detect_colors[heads])
+            else:
+                my = detect_colors[ex.src]
+            clash = (live & (kind < 0) & (my >= 0)
                      & (detect_colors[ex.nbr] == my) & _loses(ex.src, ex.nbr))
             bad = jnp.zeros((num_lanes,), jnp.bool_).at[
                 jnp.where(clash, lane, num_lanes)].set(True, mode="drop")

@@ -63,6 +63,44 @@ def owner_of_units(scan: jax.Array, budget: int) -> jax.Array:
     return jnp.cumsum(marks)
 
 
+def owner_broadcast(excl: jax.Array, budget: int, *tables) -> tuple:
+    """``table[owner(k)]`` on each of the budget's lanes ``k``, for every
+    wavefront-length ``table``, without a budget-length gather.
+
+    ``excl`` is the tasks' exclusive scan of work units (non-decreasing,
+    ``excl[0] == 0``), so the owner of unit ``k`` below the total is the
+    last task ``i`` with ``excl[i] <= k``, and lanes at or past the total
+    get the last task, as the owner's clip gives them.  Each table's
+    steps ``t[i] - t[i - 1]`` are scattered at ``excl[i]`` and one prefix
+    sum over the budget telescopes them back to ``t[owner(k)]``: tasks of
+    no units add their steps on the same lane, and starts past the budget
+    are dropped.  int32 arithmetic wraps, so the identity is exact for
+    every value (INF, -1 and INT_MIN included); bool tables ride as 0/1.
+    All tables share one scatter of ``W`` sorted updates and one prefix
+    sum, so stack the tables that are ready at the same point.
+    """
+    stack = jnp.stack([jnp.asarray(t).astype(jnp.int32) for t in tables])
+    steps = jnp.diff(stack, axis=1, prepend=0)
+    marks = jnp.zeros((len(tables), budget), jnp.int32).at[:, excl].add(
+        steps, mode="drop", indices_are_sorted=True)
+    lanes = jnp.cumsum(marks, axis=1)
+    return tuple(lanes[j] != 0 if jnp.asarray(t).dtype == jnp.bool_
+                 else lanes[j] for j, t in enumerate(tables))
+
+
+def fitting_units(scan: jax.Array, budget: int) -> jax.Array:
+    """Work units of the tasks that fit whole in the budget: the largest
+    inclusive scan value ``<= budget`` (0 if none).
+
+    A task is truncated when ``scan[i] > budget``; the scan is
+    non-decreasing, so truncation is a suffix of the wavefront and, for
+    every unit ``k`` below the total, ``~truncated[owner(k)]`` is
+    ``k < fitting_units(scan, budget)`` — a compare against a scalar in
+    place of a budget-length gather.
+    """
+    return jnp.max(jnp.where(scan <= budget, scan, 0), initial=0)
+
+
 def adjacency_of(graph) -> Tuple[jax.Array, jax.Array, object]:
     """``(row_ptr, cols, overlay)`` of a canonical or slotted graph.
 
@@ -113,6 +151,8 @@ class Expansion(NamedTuple):
     owner: jax.Array      # [W] index into the popped wavefront of the source
     valid: jax.Array      # [W] bool
     total: jax.Array      # scalar int32 — true number of work units
+    excl: jax.Array       # [tasks] first work unit of each popped task
+                          # (exclusive scan; owner_broadcast's positions)
 
 
 def chunk_degrees(heads: jax.Array, widths, valid: jax.Array,
@@ -204,22 +244,45 @@ def expand_merge_path(
         return frontier_expand(items, valid, row_ptr, col_idx, work_budget,
                                widths=widths, max_width=max_width,
                                overlay=overlay)
-    safe = jnp.where(valid, items, 0)
     deg = chunk_degrees(items, widths, valid, row_ptr)
     scan = jnp.cumsum(deg)                       # inclusive scan of degrees
-    total = scan[-1] if scan.shape[0] > 0 else jnp.int32(0)
-
-    k = jnp.arange(work_budget, dtype=jnp.int32)
     owner = owner_of_units(scan, work_budget)    # which popped item owns unit k
+    return expansion_of_owners(items, valid, row_ptr, col_idx, work_budget,
+                               deg, scan, owner, widths=widths,
+                               max_width=max_width, overlay=overlay)
+
+
+def expansion_of_owners(items, valid, row_ptr, col_idx, work_budget: int,
+                        deg, scan, owner, rank=None, widths=None,
+                        max_width: int = 1, overlay=None) -> Expansion:
+    """The work units of a wavefront whose load-balancing search is done:
+    ``owner[k]`` (the count of ``scan`` entries ``<= k``) and, where the
+    search gives it, ``rank[k]`` (the unit's edge offset in its chunk,
+    ``k - excl[owner[k]]``) — the part of the expansion both LBS backends
+    share.
+
+    Each unit's chunk head, edge base and (for chunks) width are read
+    from wavefront-sized tables by :func:`owner_broadcast`, one scatter
+    and one prefix sum, never a budget-length gather.
+    """
+    safe = jnp.where(valid, items, 0)
+    total = scan[-1] if scan.shape[0] > 0 else jnp.int32(0)
+    k = jnp.arange(work_budget, dtype=jnp.int32)
     owner = jnp.clip(owner, 0, items.shape[0] - 1)
     excl = scan - deg                            # exclusive scan
-    rank = k - excl[owner]                       # edge offset within the chunk
-    head = safe[owner]
-    src = (head if widths is None else
-           chunk_row_of(row_ptr, head, rank, widths[owner], max_width))
-    in_range = k < total
+    chunked = widths is not None and max_width > 1
     # per-unit reads index the wavefront-sized tables, not the [n] row_ptr
-    edge = (row_ptr[safe] - excl)[owner] + k
+    tables = [row_ptr[safe], safe] + ([widths] if chunked else [])
+    if rank is None:
+        tables.append(excl)
+    lanes = owner_broadcast(excl, work_budget, *tables)
+    base, head = lanes[:2]
+    if rank is None:
+        rank = k - lanes[-1]
+    src = (chunk_row_of(row_ptr, head, rank, lanes[2], max_width)
+           if chunked else head)
+    in_range = k < total
+    edge = base + rank
     nbr = gather_neighbors(row_ptr, col_idx, src, edge, overlay=overlay)
     return Expansion(
         src=jnp.where(in_range, src, 0),
@@ -227,6 +290,7 @@ def expand_merge_path(
         owner=jnp.where(in_range, owner, 0),
         valid=in_range,
         total=total,
+        excl=excl,
     )
 
 
@@ -253,13 +317,13 @@ def expand_per_item(
                            jnp.broadcast_to(safe[:, None], edge.shape),
                            edge, overlay=overlay)
     src = jnp.broadcast_to(safe[:, None], nbr.shape)
-    owner = jnp.broadcast_to(
-        jnp.arange(items.shape[0], dtype=jnp.int32)[:, None], nbr.shape
-    )
+    rows = jnp.arange(items.shape[0], dtype=jnp.int32)
+    owner = jnp.broadcast_to(rows[:, None], nbr.shape)
     return Expansion(
         src=jnp.where(in_range, src, 0).reshape(-1),
         nbr=jnp.where(in_range, nbr, 0).reshape(-1),
         owner=jnp.where(in_range, owner, 0).reshape(-1),
         valid=in_range.reshape(-1),
         total=jnp.sum(deg),
+        excl=rows * max_degree,
     )

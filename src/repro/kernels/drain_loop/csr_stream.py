@@ -175,4 +175,5 @@ def expand_stream(
         owner=jnp.where(in_range, owner, 0),
         valid=in_range,
         total=total,
+        excl=excl,
     )

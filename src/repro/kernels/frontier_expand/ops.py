@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core.backend import resolve_interpret
-from ...core.frontier import (Expansion, chunk_degrees, chunk_row_of,
-                              gather_neighbors)
+from ...core.frontier import (Expansion, chunk_degrees,
+                              expansion_of_owners)
 from .kernel import lbs_pallas
 
 
@@ -50,26 +50,12 @@ def frontier_expand(items, valid, row_ptr, col_idx, budget: int,
     both backends stay bit-identical at every granularity.
     """
     interpret = resolve_interpret(interpret)
-    safe = jnp.where(valid, items, 0)
     deg = chunk_degrees(items, widths, valid, row_ptr)
     scan = jnp.cumsum(deg)
-    total = scan[-1] if scan.shape[0] > 0 else jnp.int32(0)
-
+    # the LBS kernel only computes (owner, rank); the per-unit reads and the
+    # neighbor gather live out here (shared with the jnp backend), so a
+    # slotted graph just swaps the flat read for the two-level one
     owner, rank = lbs_pallas(scan, budget, interpret=interpret)
-    owner = jnp.clip(owner, 0, items.shape[0] - 1)
-    head = safe[owner]
-    src = (head if widths is None else
-           chunk_row_of(row_ptr, head, rank, widths[owner], max_width))
-    k = jnp.arange(budget, dtype=jnp.int32)
-    in_range = k < total
-    edge = row_ptr[safe][owner] + rank
-    # the LBS kernel only computes (owner, rank); the gather lives out here,
-    # so a slotted graph just swaps the flat read for the two-level one
-    nbr = gather_neighbors(row_ptr, col_idx, src, edge, overlay=overlay)
-    return Expansion(
-        src=jnp.where(in_range, src, 0),
-        nbr=jnp.where(in_range, nbr, 0),
-        owner=jnp.where(in_range, owner, 0),
-        valid=in_range,
-        total=total,
-    )
+    return expansion_of_owners(items, valid, row_ptr, col_idx, budget, deg,
+                               scan, owner, rank, widths=widths,
+                               max_width=max_width, overlay=overlay)

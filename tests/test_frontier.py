@@ -65,3 +65,71 @@ def test_lbs_owner_rank_invariants(degs):
         assert degs[o] > 0
         assert 0 <= rank[k] < degs[o]
         assert excl[o] + rank[k] == k
+
+
+INT_MIN, INF = -2**31, 2**31 - 1
+
+#: (degrees, budget): degree-0 tasks at the ends and in runs, a total
+#: below the budget, truncation past it, a single task, no work at all
+BROADCAST_CASES = {
+    "mixed": ([3, 0, 5, 0, 0, 2, 7, 1], 32),
+    "zero_ends": ([0, 0, 4, 0, 3, 0, 0], 16),
+    "truncating": ([5, 9, 2, 20, 4, 1], 24),
+    "truncating_zero_tail": ([6, 6, 0, 30, 0, 0], 16),
+    "w1": ([6], 8),
+    "w1_truncated": ([9], 8),
+    "no_work": ([0, 0, 0], 8),
+    "hub": (list(np.random.default_rng(5).integers(0, 6, 63)) + [90], 128),
+}
+
+
+def _owners(scan, budget, source):
+    """``owner(k)`` from either LBS backend, clipped as the expansion
+    clips it."""
+    from repro.core.frontier import owner_of_units
+    from repro.kernels.frontier_expand.kernel import lbs_pallas
+
+    if source == "jnp":
+        owner = owner_of_units(scan, budget)
+    else:
+        owner, _ = lbs_pallas(scan, budget, interpret=True)
+    return np.clip(np.asarray(owner), 0, scan.shape[0] - 1)
+
+
+@pytest.mark.parametrize("source", ["jnp", "pallas"])
+@pytest.mark.parametrize("case", sorted(BROADCAST_CASES))
+def test_owner_broadcast_equals_owner_gather(case, source):
+    """One scatter and one prefix sum give ``table[owner]`` on every lane
+    of the budget, for int32 tables at the extremes and bool tables, with
+    each table of a stacked call as it would be alone."""
+    from repro.core import fitting_units, owner_broadcast
+
+    deg, budget = BROADCAST_CASES[case]
+    deg = np.asarray(deg, np.int32)
+    w = deg.shape[0]
+    scan = jnp.cumsum(jnp.asarray(deg))
+    excl = scan - jnp.asarray(deg)
+    owner = _owners(scan, budget, source)
+    rng = np.random.default_rng(w)
+    ints = rng.integers(INT_MIN, INF, w, dtype=np.int64, endpoint=True)
+    ints[::3] = INF
+    ints[1::4] = -1
+    ints[2::5] = INT_MIN
+    ints = ints.astype(np.int32)
+    flags = rng.random(w) < 0.5
+    got_int, got_flag = owner_broadcast(excl, budget, jnp.asarray(ints),
+                                        jnp.asarray(flags))
+    assert got_int.dtype == jnp.int32 and got_flag.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(got_int), ints[owner])
+    np.testing.assert_array_equal(np.asarray(got_flag), flags[owner])
+    alone, = owner_broadcast(excl, budget, jnp.asarray(ints))
+    np.testing.assert_array_equal(np.asarray(alone), ints[owner])
+
+    # the scalar truncation cut against the owner-indexed mask, on every
+    # unit below the total (the lanes a body can make live)
+    valid = deg > 0
+    truncated = valid & (np.asarray(scan) > budget)
+    k = np.arange(budget)
+    units = k < min(int(scan[-1]), budget)
+    cut = int(fitting_units(scan, budget))
+    np.testing.assert_array_equal((k < cut)[units], ~truncated[owner][units])

@@ -123,3 +123,20 @@ def test_drain_kernels_carry_their_phase_for_v5e(one_chip, monkeypatch):
                for name in kernels), kernels
     assert any(f"/{EXPAND}/" in name for name in kernels)
     assert any(f"/{PUSH}/" in name for name in kernels)
+
+
+def test_owner_broadcast_compiles_without_gather_for_v5e(one_chip):
+    """The round bodies' per-unit reads at the cells' widths (wavefront
+    4096, budget 2^17): one scatter and one prefix sum for a stack of an
+    int32 and a bool table, with no gather left for the TPU to run."""
+    from repro.core import owner_broadcast
+
+    w = 4096
+    compiled = jax.jit(
+        lambda excl, a, b: owner_broadcast(excl, 1 << 17, a, b)
+    ).lower(_spec((w,), jnp.int32, one_chip),
+            _spec((w,), jnp.int32, one_chip),
+            _spec((w,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    assert "scatter" in text
+    assert " gather(" not in text
